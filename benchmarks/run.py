@@ -10,6 +10,8 @@ import sys
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 MODULES = [
     ("balance", "benchmarks.bench_balance"),          # Fig. 4
     ("index_build", "benchmarks.bench_index_build"),  # Table 1
@@ -29,6 +31,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, modpath in MODULES:

@@ -250,8 +250,9 @@ def serve_kernel(top_scores: jax.Array, bias: jax.Array,
 
     (B, C) cluster scores, (B, C, L) pre-sorted bias slabs, (B, C)
     lengths -> ((B, target) flat positions, (B, target) merge scores).
-    ``use_kernel=True`` runs the fused Pallas kernel (interpret mode off
-    TPU); the fallback vmaps the lax.scan form.  Both are bit-identical
+    ``use_kernel=True`` runs the fused Pallas kernel (Mosaic on a TPU,
+    interpret mode on the CPU backend); the fallback vmaps the lax.scan
+    form.  Both are bit-identical
     to the numpy heap oracle for ``exact=True``.
     """
     if use_kernel:

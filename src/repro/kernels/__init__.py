@@ -15,7 +15,9 @@ embedding_bag    — fused gather+reduce over HBM-resident tables (scalar-
 flash_attention  — causal flash attention (LM train/prefill hot spot)
 
 Each kernel: pl.pallas_call + explicit BlockSpec VMEM tiling, a jit'd
-wrapper in ops.py (interpret=True off-TPU), and a pure-jnp oracle in
-ref.py; tests sweep shapes/dtypes and assert_allclose against the oracle.
+wrapper in ops.py (Mosaic on a TPU, interpret mode on the CPU backend,
+an error on any other), and a pure-jnp oracle in ref.py; tests sweep
+shapes/dtypes against the oracle, and tests/test_tpu_compile.py compiles
+the retriever's kernels for a described v5e chip at full width.
 """
 from repro.kernels import ops, ref

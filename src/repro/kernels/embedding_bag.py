@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUMemorySpace -> MemorySpace around 0.5; support both
-_MEMORY_SPACE = getattr(pltpu, "MemorySpace", None) \
-    or getattr(pltpu, "TPUMemorySpace")
-
 
 def _embag_kernel(ids_ref, table_ref, out_ref, scratch, sem,
                   *, bb: int, bag: int):
@@ -65,7 +61,7 @@ def embedding_bag_pallas(table: jax.Array, ids: jax.Array,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bp // block_b,),
-        in_specs=[pl.BlockSpec(memory_space=_MEMORY_SPACE.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
         out_specs=pl.BlockSpec((block_b, d), lambda i, ids: (i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bag, d), jnp.float32),

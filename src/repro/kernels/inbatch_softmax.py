@@ -44,30 +44,29 @@ def _inbatch_kernel(u_ref, v_ref, bias_ref, logq_ref,
     logits = jax.lax.dot_general(
         u, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bB, bC)
-    logits = logits + bias_ref[...][None, :]
-    logits = logits - logq_ref[...][None, :]
+    logits = logits + bias_ref[...]                      # (1, bC) rows
+    logits = logits - logq_ref[...]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full((bb,), NEG, jnp.float32)
-        l_ref[...] = jnp.zeros((bb,), jnp.float32)
-        diag_ref[...] = jnp.zeros((bb,), jnp.float32)
+        m_ref[...] = jnp.full((bb, 1), NEG, jnp.float32)
+        l_ref[...] = jnp.zeros((bb, 1), jnp.float32)
+        diag_ref[...] = jnp.zeros((bb, 1), jnp.float32)
 
     m_prev = m_ref[...]
     l_prev = l_ref[...]
-    m_blk = jnp.max(logits, axis=-1)
+    m_blk = jnp.max(logits, axis=1, keepdims=True)       # (bB, 1)
     m_new = jnp.maximum(m_prev, m_blk)
     l_new = l_prev * jnp.exp(m_prev - m_new) \
-        + jnp.sum(jnp.exp(logits - m_new[:, None]), axis=-1)
+        + jnp.sum(jnp.exp(logits - m_new), axis=1, keepdims=True)
     m_ref[...] = m_new
     l_ref[...] = l_new
 
     # diagonal capture: global row index == global col index
-    rows = i * bb + jax.lax.iota(jnp.int32, bb)
-    cols = j * bc + jax.lax.iota(jnp.int32, bc)
-    hit = rows[:, None] == cols[None, :]
+    rows = i * bb + jax.lax.broadcasted_iota(jnp.int32, (bb, bc), 0)
+    cols = j * bc + jax.lax.broadcasted_iota(jnp.int32, (bb, bc), 1)
     diag_ref[...] = diag_ref[...] + jnp.sum(
-        jnp.where(hit, logits, 0.0), axis=-1)
+        jnp.where(rows == cols, logits, 0.0), axis=1, keepdims=True)
 
     @pl.when(j == n_col - 1)
     def _finish():
@@ -83,7 +82,8 @@ def inbatch_softmax_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
 
     ``return_stats=True`` -> (loss, m, l): the online-logsumexp carries
     (lse = m + log l), saved by the custom_vjp forward for the
-    flash-style backward."""
+    flash-style backward.  Per-column terms enter as (1, B) rows and
+    per-row results leave as (B, 1) columns."""
     b, d = u.shape
     if log_q is None:
         log_q = jnp.zeros((b,), jnp.float32)
@@ -92,10 +92,12 @@ def inbatch_softmax_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
     u_p = jnp.pad(u, ((0, pb), (0, 0)))
     # padded columns get -inf logits via huge logQ
     v_p = jnp.pad(v, ((0, pc), (0, 0)))
-    bias_p = jnp.pad(bias, (0, pc))
-    logq_p = jnp.pad(log_q, (0, pc), constant_values=-NEG)
+    bias_p = jnp.pad(bias, (0, pc))[None, :]
+    logq_p = jnp.pad(log_q, (0, pc), constant_values=-NEG)[None, :]
     bp, cp = b + pb, b + pc
     grid = (bp // block_b, cp // block_c)
+    row = pl.BlockSpec((1, block_c), lambda i, j: (0, j))
+    col = pl.BlockSpec((block_b, 1), lambda i, j: (i, 0))
 
     out = pl.pallas_call(
         functools.partial(_inbatch_kernel, bb=block_b, bc=block_c,
@@ -104,26 +106,16 @@ def inbatch_softmax_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
         in_specs=[
             pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
+            row, row,
         ],
-        out_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bp,), jnp.float32),   # loss
-            jax.ShapeDtypeStruct((bp,), jnp.float32),   # m carry
-            jax.ShapeDtypeStruct((bp,), jnp.float32),   # l carry
-            jax.ShapeDtypeStruct((bp,), jnp.float32),   # diag carry
-        ],
+        out_specs=[col, col, col, col],
+        out_shape=[jax.ShapeDtypeStruct((bp, 1), jnp.float32)] * 4,
         interpret=interpret,
     )(u_p, v_p, bias_p, logq_p)
+    loss, m, l = (x[:b, 0] for x in out[:3])
     if return_stats:
-        return out[0][:b], out[1][:b], out[2][:b]
-    return out[0][:b]
+        return loss, m, l
+    return loss
 
 
 # ---------------------------------------------------------------------------
@@ -138,8 +130,8 @@ def _du_kernel(u_ref, v_ref, bias_ref, logq_ref, lse_ref, acc_ref,
     z = jax.lax.dot_general(
         u, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bB, bC)
-    z = z + bias_ref[...][None, :] - logq_ref[...][None, :]
-    p = jnp.exp(z - lse_ref[...][:, None])
+    z = z + bias_ref[...] - logq_ref[...]
+    p = jnp.exp(z - lse_ref[...])                        # lse: (bB, 1)
 
     @pl.when(j == 0)
     def _init():
@@ -158,8 +150,8 @@ def _dv_kernel(u_ref, v_ref, bias_ref, logq_ref, lse_ref, g_ref,
     z = jax.lax.dot_general(
         u, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bB, bC)
-    z = z + bias_ref[...][None, :] - logq_ref[...][None, :]
-    gp = g_ref[...][:, None] * jnp.exp(z - lse_ref[...][:, None])
+    z = z + bias_ref[...] - logq_ref[...]
+    gp = g_ref[...] * jnp.exp(z - lse_ref[...])          # (bB, bC)
 
     @pl.when(i == 0)
     def _init():
@@ -169,7 +161,7 @@ def _dv_kernel(u_ref, v_ref, bias_ref, logq_ref, lse_ref, g_ref,
     dv_ref[...] += jax.lax.dot_general(
         gp, u, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bC, d)
-    db_ref[...] += jnp.sum(gp, axis=0)
+    db_ref[...] += jnp.sum(gp, axis=0, keepdims=True)    # (1, bC)
 
 
 def inbatch_softmax_bwd_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
@@ -187,12 +179,12 @@ def inbatch_softmax_bwd_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
     pc = (-b) % block_c
     u_p = jnp.pad(u, ((0, pb), (0, 0)))
     # padded rows: lse=+huge makes every p row exp(z - huge) == 0
-    lse_p = jnp.pad(lse, (0, pb), constant_values=-NEG)
-    g_p = jnp.pad(g, (0, pb))
+    lse_p = jnp.pad(lse, (0, pb), constant_values=-NEG)[:, None]
+    g_p = jnp.pad(g, (0, pb))[:, None]
     # padded cols: huge logQ makes z == -huge, p == 0 (as in the fwd)
     v_p = jnp.pad(v, ((0, pc), (0, 0)))
-    bias_p = jnp.pad(bias, (0, pc))
-    logq_p = jnp.pad(log_q, (0, pc), constant_values=-NEG)
+    bias_p = jnp.pad(bias, (0, pc))[None, :]
+    logq_p = jnp.pad(log_q, (0, pc), constant_values=-NEG)[None, :]
     bp, cp = b + pb, b + pc
     n_row, n_col = bp // block_b, cp // block_c
 
@@ -203,9 +195,9 @@ def inbatch_softmax_bwd_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
         in_specs=[
             pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, d), jnp.float32),
@@ -219,18 +211,18 @@ def inbatch_softmax_bwd_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
         in_specs=[
             pl.BlockSpec((block_b, d), lambda j, i: (i, 0)),
             pl.BlockSpec((block_c, d), lambda j, i: (j, 0)),
-            pl.BlockSpec((block_c,), lambda j, i: (j,)),
-            pl.BlockSpec((block_c,), lambda j, i: (j,)),
-            pl.BlockSpec((block_b,), lambda j, i: (i,)),
-            pl.BlockSpec((block_b,), lambda j, i: (i,)),
+            pl.BlockSpec((1, block_c), lambda j, i: (0, j)),
+            pl.BlockSpec((1, block_c), lambda j, i: (0, j)),
+            pl.BlockSpec((block_b, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((block_b, 1), lambda j, i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_c, d), lambda j, i: (j, 0)),
-            pl.BlockSpec((block_c,), lambda j, i: (j,)),
+            pl.BlockSpec((1, block_c), lambda j, i: (0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((cp, d), jnp.float32),
-            jax.ShapeDtypeStruct((cp,), jnp.float32),
+            jax.ShapeDtypeStruct((1, cp), jnp.float32),
         ],
         interpret=interpret,
     )(u_p, v_p, bias_p, logq_p, lse_p, g_p)
@@ -240,5 +232,5 @@ def inbatch_softmax_bwd_pallas(u: jax.Array, v: jax.Array, bias: jax.Array,
     g32 = g.astype(jnp.float32)
     du = g32[:, None] * (du_acc[:b] - v32)     # -delta: p minus identity
     dv = dv_acc[:b] - g32[:, None] * u32
-    dbias = db_acc[:b] - g32
+    dbias = db_acc[0, :b] - g32
     return du, dv, dbias, -dbias

@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels compile natively; everywhere else (this CPU container)
-they execute in interpret mode, which runs the kernel body in Python and
-is what the per-kernel allclose tests validate against ref.py.
+On a TPU backend the kernels compile with Mosaic.  On the CPU backend
+(``JAX_PLATFORMS=cpu``, the test suite) they run in interpret mode, which
+executes the kernel body with XLA ops and is what the per-kernel parity
+tests check against ref.py.  Any other backend raises: a kernel never
+falls back to interpret mode or to its lax path silently.
 """
 from __future__ import annotations
 
@@ -25,29 +27,34 @@ from repro.kernels.vq_assign import vq_assign_pallas
 from repro.kernels.vq_ema import ema_segment_sum_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """False on a TPU (Mosaic), True on the CPU backend, else an error."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels run on a TPU, or in interpret "
+                           f"mode on the CPU backend; not on {backend!r}")
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("block_b", "block_k"))
 def vq_assign(v: jax.Array, e: jax.Array, r: jax.Array,
               block_b: int = 256, block_k: int = 512) -> jax.Array:
     return vq_assign_pallas(v, e, r, block_b, block_k,
-                            interpret=not _on_tpu())
+                            interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("combiner", "block_b"))
 def embedding_bag(table: jax.Array, ids: jax.Array, combiner: str = "sum",
                   block_b: int = 8) -> jax.Array:
     return embedding_bag_pallas(table, ids, combiner, block_b,
-                                interpret=not _on_tpu())
+                                interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("k", "block_n"))
 def topk_dot(u: jax.Array, items: jax.Array, bias: jax.Array, k: int,
              block_n: int = 4096) -> Tuple[jax.Array, jax.Array]:
     return topk_dot_pallas(u, items, bias, k, block_n,
-                           interpret=not _on_tpu())
+                           interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("n", "block_b", "block_k"))
@@ -55,7 +62,7 @@ def cluster_rank(u: jax.Array, e: jax.Array, n: int,
                  block_b: int = 128, block_k: int = 512
                  ) -> Tuple[jax.Array, jax.Array]:
     return cluster_rank_pallas(u, e, n, block_b, block_k,
-                               interpret=not _on_tpu())
+                               interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("chunk", "target", "exact"))
@@ -64,7 +71,7 @@ def merge_serve(cluster_scores: jax.Array, bias_lists: jax.Array,
                 exact: bool = True) -> Tuple[jax.Array, jax.Array]:
     return merge_serve_pallas(cluster_scores, bias_lists, lengths,
                               chunk, target, exact,
-                              interpret=not _on_tpu())
+                              interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("chunk", "target", "exact"))
@@ -75,7 +82,7 @@ def merge_serve_ds(cluster_scores: jax.Array, bias_lists: jax.Array,
     O(C + chunk^2) per pop instead of O(C·L))."""
     return merge_serve_ds_pallas(cluster_scores, bias_lists, lengths,
                                  chunk, target, exact,
-                                 interpret=not _on_tpu())
+                                 interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("chunk", "target", "l", "exact"))
@@ -92,7 +99,7 @@ def fused_gather_rank(u: jax.Array, cluster_scores: jax.Array,
     return fused_gather_rank_pallas(u, cluster_scores, starts, lengths,
                                     limits, bias_flat, ids_flat, emb_flat,
                                     chunk, target, l, exact,
-                                    interpret=not _on_tpu())
+                                    interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("k", "block_b"))
@@ -101,7 +108,7 @@ def ema_segment_sum(v: jax.Array, assignment: jax.Array, weight: jax.Array,
                     ) -> Tuple[jax.Array, jax.Array]:
     """Eq. 7-8 EMA batch reductions as a blocked one-hot matmul."""
     return ema_segment_sum_pallas(v, assignment, weight, k, block_b,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
 
 
 @jax.jit
@@ -132,7 +139,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 256,
                     block_kv: int = 256) -> jax.Array:
     return flash_attention_pallas(q, k, v, causal, block_q, block_kv,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("block_b", "block_c"))
@@ -140,7 +147,7 @@ def inbatch_softmax(u: jax.Array, v: jax.Array, bias: jax.Array,
                     log_q: Optional[jax.Array] = None,
                     block_b: int = 256, block_c: int = 256) -> jax.Array:
     return inbatch_softmax_pallas(u, v, bias, log_q, block_b, block_c,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("block_b", "block_c"))
@@ -151,7 +158,7 @@ def inbatch_softmax_stats(u: jax.Array, v: jax.Array, bias: jax.Array,
     """Forward that also returns the online (m, l) softmax stats the
     flash-style backward recomputes logits blocks from."""
     return inbatch_softmax_pallas(u, v, bias, log_q, block_b, block_c,
-                                  interpret=not _on_tpu(),
+                                  interpret=_interpret(),
                                   return_stats=True)
 
 
@@ -162,4 +169,4 @@ def inbatch_softmax_bwd(u: jax.Array, v: jax.Array, bias: jax.Array,
     """Blocked VJP of the in-batch CE -> (du, dv, dbias, dlogq)."""
     return inbatch_softmax_bwd_pallas(u, v, bias, log_q, lse, g,
                                       block_b, block_c,
-                                      interpret=not _on_tpu())
+                                      interpret=_interpret())

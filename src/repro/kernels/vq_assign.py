@@ -21,19 +21,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _vq_assign_kernel(v_ref, e_ref, r_ref, idx_ref, val_ref, *, bk: int):
+def _vq_assign_kernel(v_ref, e_ref, ee_ref, r_ref, idx_ref, val_ref,
+                      *, bk: int):
     kt = pl.program_id(1)
     v = v_ref[...].astype(jnp.float32)                  # (bB, d)
     e = e_ref[...].astype(jnp.float32)                  # (bK, d)
-    r = r_ref[...].astype(jnp.float32)                  # (bK,)
     vv = jnp.sum(v * v, axis=-1, keepdims=True)         # (bB, 1)
-    ee = jnp.sum(e * e, axis=-1)[None, :]               # (1, bK)
     d2 = vv - 2.0 * jax.lax.dot_general(
         v, e, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) + ee        # (bB, bK) on MXU
-    scores = jnp.maximum(d2, 0.0) * r[None, :]
-    local_val = jnp.min(scores, axis=-1)
-    local_idx = (jnp.argmin(scores, axis=-1) + kt * bk).astype(jnp.int32)
+        preferred_element_type=jnp.float32) + ee_ref[...]   # (bB, bK) MXU
+    scores = jnp.maximum(d2, 0.0) * r_ref[...]
+    local_val = jnp.min(scores, axis=1, keepdims=True)  # (bB, 1)
+    # first index attaining the min == jnp.argmin's tie-break
+    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    local_idx = jnp.min(jnp.where(scores == local_val, col, bk), axis=1,
+                        keepdims=True) + kt * bk
 
     @pl.when(kt == 0)
     def _init():
@@ -54,7 +56,9 @@ def vq_assign_pallas(v: jax.Array, e: jax.Array, r: jax.Array,
     """v: (B, d), e: (K, d), r: (K,) -> assignment (B,) int32.
 
     B and K are padded to block multiples; padded clusters get r = +inf
-    scores via a huge norm so they never win.
+    scores via a huge norm so they never win.  Per-cluster terms
+    (||e_k||^2, r_k) enter as (1, K) rows and the running (min, argmin)
+    leaves as (B, 1) columns, the layouts Mosaic tiles without relayout.
     """
     b, d = v.shape
     k = e.shape[0]
@@ -67,24 +71,28 @@ def vq_assign_pallas(v: jax.Array, e: jax.Array, r: jax.Array,
         e = jnp.pad(e, ((0, pk), (0, 0)), constant_values=1e15)
         r = jnp.pad(r, (0, pk), constant_values=1.0)
     bp, kp = b + pb, k + pk
+    e32 = e.astype(jnp.float32)
+    ee = jnp.sum(e32 * e32, axis=-1)[None, :]            # (1, Kp)
+    r = r.astype(jnp.float32)[None, :]                   # (1, Kp)
 
     grid = (bp // block_b, kp // block_k)
-    out = pl.pallas_call(
+    idx, _ = pl.pallas_call(
         functools.partial(_vq_assign_kernel, bk=block_k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_k, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_k,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_k), lambda i, j: (0, j)),
+            pl.BlockSpec((1, block_k), lambda i, j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bp,), jnp.int32),
-            jax.ShapeDtypeStruct((bp,), jnp.float32),
+            jax.ShapeDtypeStruct((bp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((bp, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(v, e, r)
-    return out[0][:b]
+    )(v, e, ee, r)
+    return idx[:b, 0]

@@ -5,21 +5,22 @@
     w_add[k] = sum_{j: a_j == k} weight_j * v_j        (K, d)
     c_add[k] = sum_{j: a_j == k} weight_j              (K,)
 
-Scatter is the wrong shape for the TPU; the kernel instead streams the
-batch through VMEM in B-blocks, expands each block's assignment into a
-(bB, K) one-hot, and accumulates ``one_hot.T @ (weight * v)`` on the MXU
-into a (K, d) output block carried across grid steps — the standard
-segment-sum-as-matmul trick.  ``c_add`` rides along as a masked column
-reduction of the same one-hot.
+Scatter is the wrong shape for the TPU; the kernel instead tiles the
+codebook into K-blocks (the parallel grid axis) and streams the batch
+through VMEM in B-blocks (the reduction axis).  Each step expands the
+block's assignment row into a (bK, bB) transposed one-hot and accumulates
+``one_hot @ [weight * v | weight]`` on the MXU into a (bK, dp) output
+block carried across the B steps — the standard segment-sum-as-matmul
+trick.  ``c_add`` rides along as the extra ``weight`` column, so one
+matmul yields both reductions; the wrapper slices them apart.
 
 Summation ORDER differs from ``jax.ops.segment_sum`` (blocked matmul vs
 sequential scatter), so parity vs ``ref.ema_segment_sum_ref`` is
 allclose, not bitwise — same contract as the other reduction kernels.
 
-Padding rows carry assignment == K (one-hot all-zero) and weight 0, so
-they contribute nothing.  Like the rest of kernels/, this container
-validates in interpret mode only; the (bB, K) one-hot iota is built
-rank-2 for Mosaic.
+Padding rows carry assignment == K and weight 0; one-hot rows at and
+past K are sliced off, so padding contributes nothing.  The one-hot is a
+(bK, bB) tile, never (B, K), so VMEM stays bounded at any codebook size.
 """
 from __future__ import annotations
 
@@ -31,57 +32,55 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _ema_segment_kernel(v_ref, a_ref, wt_ref, w_ref, c_ref,
-                        *, bb: int, k: int):
-    i = pl.program_id(0)
+def _ema_segment_kernel(x_ref, a_ref, out_ref, *, bk: int):
+    kb = pl.program_id(0)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        w_ref[...] = jnp.zeros_like(w_ref)
-        c_ref[...] = jnp.zeros_like(c_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    v = v_ref[...].astype(jnp.float32)                   # (bB, d)
-    a = a_ref[...]                                       # (bB,)
-    wt = wt_ref[...].astype(jnp.float32)                 # (bB,)
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (bb, k), 1)
-    onehot = (a[:, None] == iota_k)                      # (bB, K)
-    wv = wt[:, None] * v
-    w_ref[...] += jax.lax.dot_general(
-        onehot.astype(jnp.float32), wv, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (K, d) MXU
-    c_ref[...] += jnp.sum(jnp.where(onehot, wt[:, None], 0.0), axis=0)
+    a = a_ref[...]                                       # (1, bB)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bk, a.shape[1]), 0)
+    onehot = (rows + kb * bk == a).astype(jnp.float32)   # (bK, bB)
+    out_ref[...] += jax.lax.dot_general(
+        onehot, x_ref[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)              # (bK, dp) MXU
 
 
 def ema_segment_sum_pallas(v: jax.Array, assignment: jax.Array,
                            weight: jax.Array, k: int,
-                           block_b: int = 256, interpret: bool = True
+                           block_b: int = 256, block_k: int = 1024,
+                           interpret: bool = True
                            ) -> Tuple[jax.Array, jax.Array]:
     """v: (B, d), assignment: (B,), weight: (B,) -> ((K, d), (K,))."""
     b, d = v.shape
+    w32 = weight.astype(jnp.float32)
+    # [weight * v | weight | 0...]: lane-dense, one matmul for both sums
+    dp = -(-(d + 1) // 128) * 128
+    x = jnp.concatenate([w32[:, None] * v.astype(jnp.float32),
+                         w32[:, None],
+                         jnp.zeros((b, dp - d - 1), jnp.float32)], axis=1)
     pb = (-b) % block_b
+    a = assignment.astype(jnp.int32)
     if pb:
-        v = jnp.pad(v, ((0, pb), (0, 0)))
-        # padded rows: out-of-range cluster -> all-zero one-hot
-        assignment = jnp.pad(assignment, (0, pb), constant_values=k)
-        weight = jnp.pad(weight, (0, pb))
+        x = jnp.pad(x, ((0, pb), (0, 0)))
+        # padded rows: cluster K, which no kept one-hot row matches
+        a = jnp.pad(a, (0, pb), constant_values=k)
+    block_k = min(block_k, -(-k // 8) * 8)
+    kp = -(-k // block_k) * block_k
     bp = b + pb
 
-    w_add, c_add = pl.pallas_call(
-        functools.partial(_ema_segment_kernel, bb=block_b, k=k),
-        grid=(bp // block_b,),
+    out = pl.pallas_call(
+        functools.partial(_ema_segment_kernel, bk=block_k),
+        grid=(kp // block_k, bp // block_b),
         in_specs=[
-            pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, dp), lambda j, i: (i, 0)),
+            pl.BlockSpec((1, block_b), lambda j, i: (0, i)),
         ],
-        out_specs=[
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((block_k, dp), lambda j, i: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((kp, dp), jnp.float32),
         interpret=interpret,
-    )(v, assignment.astype(jnp.int32), weight)
-    return w_add, c_add
+    )(x, a[None, :])
+    return out[:k, :d], out[:k, d]

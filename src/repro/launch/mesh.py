@@ -15,13 +15,9 @@ import jax
 
 
 def make_mesh_auto(shape, axes):
-    """jax.make_mesh with Auto axis types on jax versions that have them
-    (jax.sharding.AxisType appeared after 0.4; older jax is Auto-only)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis of type Auto."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -30,6 +30,7 @@ from repro.optim import adagrad, adamw, clip_by_global_norm, \
     multi_optimizer
 from repro.serving import extract_deltas
 from repro.train import LoopConfig, run_loop
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _route(path):
@@ -41,14 +42,15 @@ def _svq_opt():
                                     "adamw": adamw(1e-3)})
 
 
-def _svq_step_fn(cfg: SVQConfig, opt):
+def _svq_step_fn(cfg: SVQConfig, opt, use_kernel: bool = False):
     """The jitted SVQ train step shared by the offline and live loops."""
     @jax.jit
     def step_fn(state, batch):
         imp = {k: jnp.asarray(v) for k, v in batch["imp"].items()}
         cand = {k: jnp.asarray(v) for k, v in batch["cand"].items()}
         grads, new_index, metrics = retriever.train_step(
-            state["params"], state["index"], cfg, imp, cand)
+            state["params"], state["index"], cfg, imp, cand,
+            use_kernel=use_kernel)
         grads, gn = clip_by_global_norm(grads, 10.0)
         params, opt_state = opt.update(grads, state["opt"],
                                        state["params"], state["step"])
@@ -63,13 +65,18 @@ def _svq_step_fn(cfg: SVQConfig, opt):
 
 def train_svq(cfg: SVQConfig, stream: RecsysStream, n_steps: int,
               batch: int, ckpt_dir: str | None = None,
-              log_every: int = 0, seed: int = 0):
-    """-> (params, index_state, loop_result)."""
+              log_every: int = 0, seed: int = 0, use_kernel: bool = False,
+              stats=None):
+    """-> (params, index_state, loop_result).
+
+    ``use_kernel`` runs the train step's Pallas kernels (VQ assignment,
+    EMA segment sums, in-batch softmax); ``stats`` (a ServeStats)
+    receives per-step ``train_step`` times."""
     opt = _svq_opt()
     params, index = retriever.init(jax.random.PRNGKey(seed), cfg)
     state = {"params": params, "index": index, "opt": opt.init(params),
              "step": jnp.zeros((), jnp.int32)}
-    step_fn = _svq_step_fn(cfg, opt)
+    step_fn = _svq_step_fn(cfg, opt, use_kernel)
 
     def batch_iter(step):
         return {"imp": stream.impression_batch(batch),
@@ -77,7 +84,7 @@ def train_svq(cfg: SVQConfig, stream: RecsysStream, n_steps: int,
 
     loop_cfg = LoopConfig(n_steps=n_steps, ckpt_dir=ckpt_dir,
                           ckpt_every=max(n_steps // 4, 1),
-                          log_every=log_every, sync_every=10)
+                          log_every=log_every, sync_every=10, stats=stats)
     res = run_loop(step_fn, state, batch_iter, loop_cfg)
     return res.state["params"], res.state["index"], res
 
@@ -248,6 +255,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--log-every", type=int, default=20)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "svq":
         cfg = get_smoke("svq").with_(n_clusters=256, n_items=20000,

@@ -93,10 +93,7 @@ def moe_ffn_shard_map(x: jax.Array, router_w: jax.Array,
     With fsdp_axis set (llama4), expert weights arrive D-sharded and are
     all-gathered layer-locally (standard FSDP weight gather).
     """
-    try:
-        from jax import shard_map
-    except ImportError:            # pre-0.5 jax spelling
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec
     g, t, d = x.shape
     e = router_w.shape[-1]

@@ -201,11 +201,21 @@ def sharded_stage_rank(params: Params, state: IndexState, cfg: SVQConfig,
 
     # ---- stage 1: per-shard indexing step (local cluster ranking) ------
     e_all = state.vq.embeddings()
+    # The lax path scores the whole codebook in ONE dot and slices it per
+    # shard: XLA's CPU dot rounds differently for different output
+    # widths, so per-shard dots would drift an ulp from the single-device
+    # scores.  The kernel scores fixed (block_b, block_k) tiles either way.
+    scores = None if use_kernel else u @ e_all.T
     vals_l, ids_l = [], []
     with trace.annotate("cluster_rank"):
         for d in range(D):
-            e_d = jax.lax.slice_in_dim(e_all, d * ks, (d + 1) * ks)
-            v, i = rank_codebook(e_d, u, n_local, use_kernel=use_kernel)
+            if use_kernel:
+                e_d = jax.lax.slice_in_dim(e_all, d * ks, (d + 1) * ks)
+                v, i = rank_codebook(e_d, u, n_local, use_kernel=True)
+            else:
+                v, i = jax.lax.top_k(
+                    jax.lax.slice_in_dim(scores, d * ks, (d + 1) * ks,
+                                         axis=1), n_local)
             vals_l.append(v)
             ids_l.append(i + jnp.int32(d * ks))
     # shard-order concat: ties resolve to the lower global cluster id,

@@ -8,7 +8,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def current_mesh() -> Optional[Mesh]:
-    m = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
     try:
         from jax._src import mesh as mesh_lib
         ctx = mesh_lib.thread_resources.env.physical_mesh
