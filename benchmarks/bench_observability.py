@@ -7,14 +7,13 @@ host drift hits both equally:
 
   disabled  plain RetrievalService: no tracer, no registry,
   obs_on    production observability: a sampling Tracer (every
-            ``SAMPLE_EVERY``-th request runs the staged span path),
+            ``SAMPLE_EVERY``-th request records its host spans),
             ``register_metrics()`` into a MetricRegistry, a live HTTP
             exporter being scraped during the run.
 
 Acceptance: obs_on p99 within 5% of disabled (``within_5pct``).  The
-honest per-TRACED-request cost (the staged path syncs per stage, so a
-sampled request pays real overhead — that is why sampling exists) is
-reported separately, as is the scrape cost.
+per-TRACED-request cost (a sampled request runs the same serve jit and
+also records its spans) is reported separately, as is the scrape cost.
 
 Satellite: the batched-numpy ``apply_deltas_batched`` vs the sequential
 ``apply_deltas_loop`` reference on identical delta streams (bit-parity
@@ -33,7 +32,7 @@ Results land in ``BENCH_observability.json``:
                                is what within_5pct accepts on — pooled
                                p99s are one-hiccup-decides on a shared
                                host
-  rows.traced_request          fused vs staged mean (ms), overhead_x,
+  rows.traced_request          untraced vs traced mean (ms), overhead_x,
                                spans recorded per traced request
   rows.scrape                  scrapes completed during the run, mean ms
   rows.probe_overhead          shadow quality probes (obs/quality.py)
@@ -91,7 +90,6 @@ def _bench_serve(tr, batch):
     # warm both jit paths outside the measurement window
     svc_off.serve_batch(batch)
     svc_on.serve_batch(batch)
-    svc_on.serve_batch(batch, span_sink=[])      # staged compile
     rounds_off, rounds_on, scrape_ms = [], [], []
     with start_exporter(reg, port=0, tracer=tracer) as ex:
         url = ex.url("/metrics")
@@ -109,16 +107,16 @@ def _bench_serve(tr, batch):
                        if ln and not ln.startswith("#"))
     lat_off = [x for r in rounds_off for x in r]
     lat_on = [x for r in rounds_on for x in r]
-    # honest per-traced-request cost: fused vs staged, same service
-    fused, staged = [], []
+    # per-traced-request cost: untraced vs traced, same service
+    untraced, traced = [], []
     for _ in range(sz(20, 5)):
         t0 = time.perf_counter()
         svc_on.serve_batch(batch, span_sink=None)
-        fused.append(time.perf_counter() - t0)
+        untraced.append(time.perf_counter() - t0)
         sink = []
         t0 = time.perf_counter()
         svc_on.serve_batch(batch, span_sink=sink)
-        staged.append(time.perf_counter() - t0)
+        traced.append(time.perf_counter() - t0)
     n_spans = len(sink)
     p99_off, p99_on = _p(lat_off, 99), _p(lat_on, 99)
     # single pooled p99s are hostile to a shared, noisy host: one
@@ -139,9 +137,10 @@ def _bench_serve(tr, batch):
                                              for x in per_round],
                        within_5pct=bool(inflation <= 5.0)),
         traced_request=dict(
-            fused_mean_ms=round(float(np.mean(fused)) * 1e3, 4),
-            staged_mean_ms=round(float(np.mean(staged)) * 1e3, 4),
-            overhead_x=round(float(np.mean(staged) / np.mean(fused)), 2),
+            untraced_mean_ms=round(float(np.mean(untraced)) * 1e3, 4),
+            traced_mean_ms=round(float(np.mean(traced)) * 1e3, 4),
+            overhead_x=round(float(np.mean(traced) / np.mean(untraced)),
+                             2),
             spans=n_spans,
             traces_finished=tracer.n_finished),
         scrape=dict(n_scrapes=len(scrape_ms),

@@ -47,8 +47,10 @@ def main() -> None:
 
     print("== serving ==")
     # delta_spare reserves per-cluster headroom for live delta appends;
-    # the tracer samples every 3rd request through the staged serve path
-    # (per-stage spans; numerics identical to the fused jit)
+    # the tracer samples every 3rd request: it runs the same serve jit
+    # and records its host spans (serve.put / serve.dispatch /
+    # serve.fetch); per-stage device times come from a jax.profiler
+    # trace, by named scope
     svc = RetrievalService(cfg, params, index, delta_spare=32,
                            tracer=Tracer(capacity=128, sample_every=3))
     users = np.arange(16, dtype=np.int32)

@@ -125,14 +125,14 @@ def build_serving_index(store: AssignmentStore, n_clusters: int,
     cl = jnp.where(occupied, store.cluster, n_clusters)
     if use_kernel:
         from repro.kernels import ops as kops
-        with trace.annotate("index_sort"):
+        with trace.span("index_sort"):
             order = kops.index_sort(cl, store.item_bias)
         cl_sorted = cl[order]
         offsets = jnp.searchsorted(
             cl_sorted, jnp.arange(n_clusters + 1), side="left")
     else:
         from repro.kernels import ref as kref
-        with trace.annotate("index_sort"):
+        with trace.span("index_sort"):
             order = kref.index_sort_ref(cl, store.item_bias)
         cl_sorted = cl[order]
         counts = jax.ops.segment_sum(

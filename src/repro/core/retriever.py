@@ -299,14 +299,15 @@ def serve_stage_rank(params: Params, state: IndexState, cfg: SVQConfig,
                      use_kernel: bool = False) -> Dict[str, jax.Array]:
     """Stage 1 of serve: user tower + Eq. 11 cluster ranking.
 
-    The serve pipeline is split into three stage functions so the
-    observability layer can time each stage per request (three jit calls
-    with a sync between them); ``serve`` composes them op-for-op, so the
-    fused path's numerics are unchanged by the split.
+    ``serve`` composes the three stage functions under one jit; each
+    part of the step runs in a named scope (``trace.annotate``), which
+    the device trace reads as per-stage time.
     """
-    user_feat, hist_emb = user_features(params, batch["user_id"],
-                                        batch["hist"])
-    u = jax.vmap(lambda tw: mlp(tw, user_feat))(params["user_towers"])[task]
+    with trace.annotate("user_tower"):
+        user_feat, hist_emb = user_features(params, batch["user_id"],
+                                            batch["hist"])
+        u = jax.vmap(lambda tw: mlp(tw, user_feat))(
+            params["user_towers"])[task]
     with trace.annotate("cluster_rank"):
         top_scores, top_clusters = rank_clusters(state, u,
                                                  cfg.clusters_per_query,
@@ -330,11 +331,16 @@ def serve_stage_merge(cfg: SVQConfig, index: astore.ServingIndex,
     float accumulation order.
     """
     top_scores, top_clusters = s1["top_scores"], s1["top_clusters"]
-    starts = index.offsets[top_clusters]                     # (B, C)
-    counts = index.counts[top_clusters]       # live prefix (tombstone-aware)
     L = items_per_cluster
-    lengths = jnp.minimum(counts, L)
     S = cfg.candidates_out
+    with trace.annotate("slab_gather"):
+        starts = index.offsets[top_clusters]                 # (B, C)
+        counts = index.counts[top_clusters]   # live prefix (tombstone-aware)
+        lengths = jnp.minimum(counts, L)
+        if not fused:
+            slab = starts[..., None] + jnp.arange(L)[None, None, :]
+            slab = jnp.minimum(slab, index.n_items - 1)      # (B, C, L)
+            bias = index.item_bias[slab]                     # (B, C, L)
 
     if fused:
         limits = jnp.full_like(starts, index.n_items - 1)
@@ -346,31 +352,29 @@ def serve_stage_merge(cfg: SVQConfig, index: astore.ServingIndex,
         return dict(cand_ids=cand_ids, valid=pos >= 0,
                     merge_scores=msort_scores, exact_scores=exact_scores)
 
-    slab = starts[..., None] + jnp.arange(L)[None, None, :]  # (B, C, L)
-    slab = jnp.minimum(slab, index.n_items - 1)
-    bias = index.item_bias[slab]                             # (B, C, L)
-
     # ---- Alg. 1 merge sort over (cluster personality + item bias) ------
     with trace.annotate("merge_serve"):
         pos, msort_scores = serve_kernel(top_scores, bias, lengths,
                                          cfg.chunk_size, S,
                                          use_kernel=use_kernel)
-    valid = pos >= 0
-    c_idx = jnp.clip(pos, 0) // L
-    i_idx = jnp.clip(pos, 0) % L
-    flat = jnp.take_along_axis(
-        slab.reshape(slab.shape[0], -1),
-        (c_idx * L + i_idx).astype(jnp.int32), axis=1)       # (B, S)
-    cand_ids = index.item_ids[flat]
-    # exact Eq. 11 candidate score u.v + bias from the index payload —
-    # what the fused path computes in-kernel (the ranking step still
-    # re-embeds candidates from the model tables in stage 3)
-    exact_scores = jnp.where(
-        valid,
-        jnp.einsum("bsd,bd->bs", index.item_emb[flat].astype(jnp.float32),
-                   s1["u"].astype(jnp.float32))
-        + index.item_bias[flat].astype(jnp.float32),
-        merge_sort.NEG)
+    with trace.annotate("cand_gather"):
+        valid = pos >= 0
+        c_idx = jnp.clip(pos, 0) // L
+        i_idx = jnp.clip(pos, 0) % L
+        flat = jnp.take_along_axis(
+            slab.reshape(slab.shape[0], -1),
+            (c_idx * L + i_idx).astype(jnp.int32), axis=1)   # (B, S)
+        cand_ids = index.item_ids[flat]
+        # exact Eq. 11 candidate score u.v + bias from the index payload
+        # — what the fused path computes in-kernel (the ranking step
+        # still re-embeds candidates from the model tables in stage 3)
+        exact_scores = jnp.where(
+            valid,
+            jnp.einsum("bsd,bd->bs",
+                       index.item_emb[flat].astype(jnp.float32),
+                       s1["u"].astype(jnp.float32))
+            + index.item_bias[flat].astype(jnp.float32),
+            merge_sort.NEG)
     return dict(cand_ids=cand_ids, valid=valid,
                 merge_scores=msort_scores, exact_scores=exact_scores)
 
@@ -382,21 +386,23 @@ def serve_stage_ranking(params: Params, cfg: SVQConfig,
     ("VQ Two-tower" or "VQ Complicated" per cfg.ranking, §3.5)."""
     user_feat, hist_emb = s1["user_feat"], s1["hist_emb"]
     cand_ids, valid = s2["cand_ids"], s2["valid"]
-    cand_cate = jnp.zeros_like(cand_ids)      # cate refetched via tables
-    item_feat = item_features(params, cand_ids, cand_cate)
-    cross = (item_feat[..., :cfg.item_embed_dim]
-             * user_feat[..., None, -cfg.item_embed_dim:])
-    rscores = ranking.ranking_scores(params["rank"], cfg, user_feat,
-                                     item_feat, hist_emb, cross)[task]
-    rscores = jnp.where(valid, rscores, merge_sort.NEG)
-    order = jnp.argsort(-rscores, axis=-1)
-    return dict(
-        item_ids=jnp.take_along_axis(cand_ids, order, axis=1),
-        scores=jnp.take_along_axis(rscores, order, axis=1),
-        merge_scores=s2["merge_scores"],
-        exact_scores=s2["exact_scores"],
-        index_ids=cand_ids,
-        valid=jnp.take_along_axis(valid, order, axis=1))
+    with trace.annotate("rank_features"):
+        cand_cate = jnp.zeros_like(cand_ids)  # cate refetched via tables
+        item_feat = item_features(params, cand_ids, cand_cate)
+        cross = (item_feat[..., :cfg.item_embed_dim]
+                 * user_feat[..., None, -cfg.item_embed_dim:])
+    with trace.annotate("rank_score"):
+        rscores = ranking.ranking_scores(params["rank"], cfg, user_feat,
+                                         item_feat, hist_emb, cross)[task]
+        rscores = jnp.where(valid, rscores, merge_sort.NEG)
+        order = jnp.argsort(-rscores, axis=-1)
+        return dict(
+            item_ids=jnp.take_along_axis(cand_ids, order, axis=1),
+            scores=jnp.take_along_axis(rscores, order, axis=1),
+            merge_scores=s2["merge_scores"],
+            exact_scores=s2["exact_scores"],
+            index_ids=cand_ids,
+            valid=jnp.take_along_axis(valid, order, axis=1))
 
 
 def serve(params: Params, state: IndexState, cfg: SVQConfig,

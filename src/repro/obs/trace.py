@@ -1,9 +1,13 @@
 """Lightweight request tracing for the serving pipeline.
 
 One *trace* is the life of one serve request: a unique trace ID plus
-the named *spans* it passed through — queue wait in the micro-batcher,
-the per-shard cluster-ranking stage, the Alg. 1 merge, and the ranking
-step (§3.4's "scoring-then-ranking" pipeline, observable per request).
+the named *spans* it passed through — its queue wait, then the host
+phases of the micro-batcher flush that served it (``batcher.*``) and of
+``serve_batch`` (``serve.put``, ``serve.dispatch``, ``serve.fetch``),
+every one carrying the flush's sequence number, so a request joins the
+same flush's spans in a device profile.  A traced request runs the same
+jitted program as every other request.
+
 Traces are cheap host objects: a span is a (name, start, end, thread)
 record on ``time.monotonic()``; recording one is two clock reads and a
 list append, so the serve path stays benchmarkably flat when tracing is
@@ -17,15 +21,24 @@ the concurrency suite asserts from N threads.
 ``export_chrome_trace()`` emits Chrome trace-event JSON (the
 "traceEvents" array form) loadable in Perfetto / chrome://tracing;
 every event carries its trace ID in ``args`` so one request's spans
-can be filtered across threads.
+can be filtered across threads.  Those timestamps are on the monotonic
+clock, not on a device profile's.
 
-``annotate(name)`` is the optional device bridge: when enabled it wraps
-a code region in ``jax.profiler.TraceAnnotation`` (host timeline of a
-device profile) AND ``jax.named_scope`` (HLO metadata), so spans taken
-around the kernel-dispatch sites (``serve_kernel``, ``cluster_rank``,
-``merge_serve``, ``index_sort``) line up with device traces captured by
-``jax.profiler``.  Disabled (the default) it is a no-op with no jax
-call in the hot path.
+Two primitives put the program's own names into a ``jax.profiler``
+trace, both off unless ``enable_device_annotations()`` was called:
+
+- ``span(name, sink, **args)`` is the host primitive.  Around eager host
+  work it opens a ``jax.profiler.TraceAnnotation``, which the profiler
+  writes into its own trace on the device ops' clock, with ``args`` as
+  the event's arguments; given a ``sink`` list it also appends a
+  monotonic-clock ``Span`` for the ``Tracer``.
+- ``annotate(name)`` is the device scope: a ``jax.named_scope`` inside
+  a jitted function, which names the function's ops in their HLO
+  metadata (a device op's ``tf_op`` in the trace).  It changes metadata
+  only, never an op.  Around code that is not traced it names nothing:
+  use ``span`` there.
+
+Both make no jax call when annotations are off and no sink is given.
 """
 from __future__ import annotations
 
@@ -46,11 +59,24 @@ _DEVICE_ANNOTATIONS = False
 
 
 def enable_device_annotations(on: bool = True) -> None:
-    """Bridge ``annotate`` regions into jax device profiles (opt-in;
-    must be set before the annotated functions are traced/compiled for
-    the ``named_scope`` half to reach the HLO)."""
+    """Write ``span`` and ``annotate`` names into jax profiles (opt-in;
+    set it before the annotated functions are traced and compiled, or
+    their ops carry no scope).
+
+    It also puts op metadata into JAX's persistent compile-cache key.
+    The key leaves metadata out by default, so a program compiled with
+    other scopes, or none, would be loaded from the cache and its ops
+    would carry those names in the profile.  The metadata then holds op
+    names only, no Python traceback, so the key does not change with
+    the checkout's path or the calling script.  Turning annotations off
+    restores JAX's defaults for both settings."""
     global _DEVICE_ANNOTATIONS
     _DEVICE_ANNOTATIONS = bool(on)
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      _DEVICE_ANNOTATIONS)
+    jax.config.update("jax_traceback_in_locations_limit",
+                      0 if _DEVICE_ANNOTATIONS else 10)
 
 
 def device_annotations_enabled() -> bool:
@@ -59,13 +85,46 @@ def device_annotations_enabled() -> bool:
 
 @contextlib.contextmanager
 def annotate(name: str):
-    """No-op unless ``enable_device_annotations()`` was called."""
+    """Device scope: ``jax.named_scope(name)`` around code a jit traces,
+    so its ops carry ``name`` in their metadata.  No-op unless
+    ``enable_device_annotations()`` was called before the tracing."""
     if not _DEVICE_ANNOTATIONS:
         yield
         return
     import jax
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
+    with jax.named_scope(name):
         yield
+
+
+@contextlib.contextmanager
+def span(name: str, sink: Optional[List["Span"]] = None,
+         **args) -> Iterator[Dict[str, object]]:
+    """Host span around eager work: a ``jax.profiler.TraceAnnotation``
+    with ``args`` as event arguments when device annotations are on,
+    and a monotonic-clock ``Span`` appended to ``sink`` when it is a
+    list.  Yields ``args``: keys the body adds (values known only at the
+    end) reach both copies.  With annotations off and no sink it is one
+    branch."""
+    if not _DEVICE_ANNOTATIONS and sink is None:
+        yield args
+        return
+    ann = None
+    if _DEVICE_ANNOTATIONS:
+        import jax
+        ann = jax.profiler.TraceAnnotation(name, **args)
+        ann.__enter__()
+    first = set(args)
+    t0 = time.monotonic()
+    try:
+        yield args
+    finally:
+        if ann is not None:
+            late = {k: v for k, v in args.items() if k not in first}
+            if late:
+                ann.set_metadata(**late)
+            ann.__exit__(None, None, None)
+        if sink is not None:
+            sink.append(make_span(name, t0, **args))
 
 
 # -- spans + traces ---------------------------------------------------------

@@ -18,6 +18,17 @@ bucket, runs ``serve_fn`` ONCE, and scatters row slices back to each
 waiting future.  Queue-wait and flush latencies are recorded into the
 shared ``ServeStats`` stage histograms, so the p99 seen by a *request*
 (wait + serve) is observable, not just the p99 of the jit call.
+
+The worker's phases run in host spans (``obs.trace.span``), one after
+another on its thread: ``batcher.wait`` (waiting for a trigger, the
+trigger scan included), ``batcher.take`` (popping the group, with
+``queued``, the queue's length before it), ``batcher.assemble``
+(queue-wait records, concatenation and padding), the serve function's
+own spans, then
+``batcher.scatter`` (traces, stats and futures).  Each carries
+``flush``, the flush's sequence number; ``take``, ``assemble`` and
+``scatter`` also ``task``, ``rows`` (real rows) and ``bucket`` (padded
+rows), and ``wait`` the ``task`` its trigger chose.
 """
 from __future__ import annotations
 
@@ -111,14 +122,18 @@ class MicroBatcher:
         # serve fns that accept ``n_valid`` get the REAL row count, so
         # their request counters exclude the bucket-padding rows; fns
         # that accept ``span_sink`` get per-flush stage spans back, which
-        # are fanned out to every traced request in the flush group
+        # are fanned out to every traced request in the flush group; fns
+        # that accept ``flush`` get the flush's sequence number for
+        # their own spans
         try:
             sig_params = inspect.signature(serve_fn).parameters
             self._pass_n_valid = "n_valid" in sig_params
             self._pass_span_sink = "span_sink" in sig_params
+            self._pass_flush = "flush" in sig_params
         except (TypeError, ValueError):            # pragma: no cover
             self._pass_n_valid = False
             self._pass_span_sink = False
+            self._pass_flush = False
         self.tracer = tracer
         self.max_batch = max_batch
         self.max_delay_s = max_delay_s
@@ -134,6 +149,7 @@ class MicroBatcher:
         self.padded_rows = 0
         self.served_rows = 0
         self.shapes_seen: set = set()
+        self._next_flush = 0        # sequence number of the next flush
 
         self._pending: List[_Pending] = []
         self._cond = threading.Condition()
@@ -175,37 +191,56 @@ class MicroBatcher:
     # -- worker side -------------------------------------------------------
     def _run(self) -> None:
         while True:
+            seq = self._next_flush
+            # the flush's host spans, kept for the traced requests in it
+            sink = [] if self.tracer is not None else None
             with self._cond:
-                while True:
-                    if self._pending:
-                        oldest = self._pending[0]
-                        # the size trigger scans EVERY task group (a
-                        # full group must not be head-of-line blocked
-                        # behind another task's lone aging request);
-                        # one O(P) pass, the queue can be long
-                        rows_by_task: Dict[int, int] = {}
-                        size_task = None
-                        for p in self._pending:
-                            r = rows_by_task.get(p.task, 0) + p.rows
-                            rows_by_task[p.task] = r
-                            if r >= self.max_batch:
-                                size_task = p.task
-                                break
-                        if size_task is not None:
-                            flush_task, deadline_flush = size_task, False
-                            break
-                        wait_left = (oldest.t_enqueue + self.max_delay_s
-                                     - time.monotonic())
-                        if wait_left <= 0 or self._closed:
-                            flush_task, deadline_flush = oldest.task, True
-                            break
-                        self._cond.wait(timeout=wait_left)
-                    elif self._closed:
+                with trace_lib.span("batcher.wait", sink, flush=seq) as wait:
+                    flush_task, deadline_flush = self._wait_trigger()
+                    if flush_task is None:
                         return
-                    else:
-                        self._cond.wait()
-                group = self._take_group(flush_task)
-            self._flush(group, flush_task, deadline_flush)
+                    wait["task"] = flush_task
+                with trace_lib.span("batcher.take", sink, flush=seq,
+                                    task=flush_task,
+                                    queued=len(self._pending)) as take:
+                    group = self._take_group(flush_task)
+                    rows = sum(p.rows for p in group)
+                    take.update(rows=rows, bucket=next(
+                        b for b in self.buckets if b >= rows))
+            self._next_flush += 1
+            self._flush(group, flush_task, deadline_flush, sink,
+                        dict(flush=seq, task=flush_task, rows=rows,
+                             bucket=take["bucket"]))
+
+    def _wait_trigger(self) -> Tuple[Optional[int], bool]:
+        """Wait (cond held) until a flush is due -> (task, deadline
+        flush), or (None, False) once closed with nothing queued."""
+        while True:
+            if self._pending:
+                oldest = self._pending[0]
+                # the size trigger scans EVERY task group (a full group
+                # must not be head-of-line blocked behind another task's
+                # lone aging request); one O(P) pass, the queue can be
+                # long
+                rows_by_task: Dict[int, int] = {}
+                size_task = None
+                for p in self._pending:
+                    r = rows_by_task.get(p.task, 0) + p.rows
+                    rows_by_task[p.task] = r
+                    if r >= self.max_batch:
+                        size_task = p.task
+                        break
+                if size_task is not None:
+                    return size_task, False
+                wait_left = (oldest.t_enqueue + self.max_delay_s
+                             - time.monotonic())
+                if wait_left <= 0 or self._closed:
+                    return oldest.task, True
+                self._cond.wait(timeout=wait_left)
+            elif self._closed:
+                return None, False
+            else:
+                self._cond.wait()
 
     def _take_group(self, task: int) -> List[_Pending]:
         """Pop FIFO requests of ``task`` until max_batch rows (cond held)."""
@@ -220,38 +255,42 @@ class MicroBatcher:
         return group
 
     def _flush(self, group: List[_Pending], task: int,
-               deadline_flush: bool) -> None:
+               deadline_flush: bool, sink: Optional[List[trace_lib.Span]],
+               args: Dict[str, int]) -> None:
         t_flush = time.monotonic()
-        rows = sum(p.rows for p in group)
-        for p in group:
-            self.stats.stage("queue_wait").record(t_flush - p.t_enqueue)
-            if p.trace is not None:
-                p.trace.add_span(trace_lib.make_span(
-                    "queue_wait", p.t_enqueue, t_flush))
-        # one stage-span sink per flush: the jit call is shared, so its
-        # stage spans are shared verbatim by every traced request in the
-        # group (each trace re-stamps them with its own trace ID at
-        # export time)
+        rows, bucket = args["rows"], args["bucket"]
+        # the flush's spans are shared verbatim by every traced request
+        # in the group (each trace re-stamps them with its own trace ID
+        # at export time); the serve fn adds its own only when one is
+        # traced
         traced = [p for p in group if p.trace is not None]
-        sink = [] if (traced and self._pass_span_sink) else None
         try:
             # batch assembly stays inside the error path: a malformed
             # request (mismatched keys/shapes across the group) must
             # fail ITS futures, not kill the worker thread
-            bucket = next(b for b in self.buckets if b >= rows)
-            keys = group[0].batch.keys()
-            batch = {}
-            for k in keys:
-                cat = np.concatenate([p.batch[k] for p in group], axis=0)
-                if bucket > rows:
-                    # pad by repeating row 0: valid ids, constant shape
-                    pad = np.repeat(cat[:1], bucket - rows, axis=0)
-                    cat = np.concatenate([cat, pad], axis=0)
-                batch[k] = cat
+            with trace_lib.span("batcher.assemble", sink, **args):
+                for p in group:
+                    self.stats.stage("queue_wait").record(
+                        t_flush - p.t_enqueue)
+                    if p.trace is not None:
+                        p.trace.add_span(trace_lib.make_span(
+                            "queue_wait", p.t_enqueue, t_flush, **args))
+                batch = {}
+                for k in group[0].batch.keys():
+                    cat = np.concatenate([p.batch[k] for p in group],
+                                         axis=0)
+                    if bucket > rows:
+                        # pad by repeating row 0: valid ids, constant
+                        # shape
+                        pad = np.repeat(cat[:1], bucket - rows, axis=0)
+                        cat = np.concatenate([cat, pad], axis=0)
+                    batch[k] = cat
             kwargs = {}
             if self._pass_n_valid:
                 kwargs["n_valid"] = rows
-            if sink is not None:
+            if self._pass_flush:
+                kwargs["flush"] = args["flush"]
+            if traced and self._pass_span_sink:
                 kwargs["span_sink"] = sink
             out = self._serve_fn(batch, task, **kwargs)
         except BaseException as e:
@@ -261,22 +300,24 @@ class MicroBatcher:
                     p.trace.attrs["error"] = repr(e)
                     self.tracer.finish(p.trace)
             return
-        for p in traced:
-            if sink:
+        with trace_lib.span("batcher.scatter", **args):
+            for p in traced:
                 p.trace.spans.extend(sink)
-            p.trace.attrs["flush_rows"] = rows
-            self.tracer.finish(p.trace)
-        self.stats.stage("batcher_flush").record(time.monotonic() - t_flush)
-        self.n_flushes += 1
-        if deadline_flush:
-            self.n_deadline_flushes += 1
-        else:
-            self.n_size_flushes += 1
-        self.padded_rows += bucket - rows
-        self.served_rows += rows
-        self.shapes_seen.add(bucket)
-        lo = 0
-        for p in group:
-            sl = {k: v[lo:lo + p.rows] for k, v in out.items()}
-            lo += p.rows
-            p.future._set(sl)
+                p.trace.attrs["flush"] = args["flush"]
+                p.trace.attrs["flush_rows"] = rows
+                self.tracer.finish(p.trace)
+            self.stats.stage("batcher_flush").record(
+                time.monotonic() - t_flush)
+            self.n_flushes += 1
+            if deadline_flush:
+                self.n_deadline_flushes += 1
+            else:
+                self.n_size_flushes += 1
+            self.padded_rows += bucket - rows
+            self.served_rows += rows
+            self.shapes_seen.add(bucket)
+            lo = 0
+            for p in group:
+                sl = {k: v[lo:lo + p.rows] for k, v in out.items()}
+                lo += p.rows
+                p.future._set(sl)

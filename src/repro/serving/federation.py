@@ -280,7 +280,7 @@ class FederationRouter:
             t0 = time.monotonic()
             # span_sink is per-backend only on the fan-out path; the
             # short-circuit backend receives the router's sink directly
-            # so its own stage spans (SVQ staged serve) keep flowing
+            # so its own spans (the SVQ service's serve.*) keep flowing
             inner_sink = span_sink if len(backends) == 1 else None
             cand = backend.serve(batch, k, task=task, n_valid=n_valid,
                                  span_sink=inner_sink)

@@ -72,10 +72,9 @@ class RetrievalService:
         # tolerance-contract opt-in, sequential/replicated stays the
         # oracle.  Only meaningful with n_shards + mesh.
         self.rank_parallel = rank_parallel
-        # request tracer (obs/trace.py): sampled requests run the STAGED
-        # serve path (three jit calls with a sync between stages) so
-        # their spans carry real per-stage wall times; unsampled requests
-        # keep the fused single-jit path.
+        # request tracer (obs/trace.py): a sampled request runs the same
+        # jitted serve as every other and collects the host spans of its
+        # flush (batcher.*, serve.*)
         self.tracer = tracer
         self.stats = ServeStats()
         self._lock = threading.Lock()
@@ -99,47 +98,13 @@ class RetrievalService:
                     items_per_cluster=items_per_cluster, task=task,
                     use_kernel=use_kernel, fused=fused, mesh=mesh,
                     rank_parallel=rank_parallel)
-
-            def _stage_rank(p, s, idx, b, task):
-                return sharding_lib.sharded_stage_rank(
-                    p, s, cfg, idx, b, task=task,
-                    use_kernel=use_kernel, mesh=mesh)
-
-            def _stage_merge(idx, s1):
-                return sharding_lib.sharded_stage_merge(
-                    cfg, idx, s1, items_per_cluster=items_per_cluster,
-                    use_kernel=use_kernel, fused=fused, mesh=mesh)
-
-            def _stage_ranking(p, s1, s2, task):
-                return sharding_lib.sharded_stage_ranking(
-                    p, cfg, s1, s2, task=task, mesh=mesh,
-                    rank_parallel=rank_parallel)
         else:
             def _serve(p, s, idx, b, task):
                 return retriever.serve(
                     p, s, cfg, idx, b,
                     items_per_cluster=items_per_cluster, task=task,
                     use_kernel=use_kernel, fused=fused)
-
-            def _stage_rank(p, s, idx, b, task):
-                del idx                        # uniform staged signature
-                return retriever.serve_stage_rank(
-                    p, s, cfg, b, task=task, use_kernel=use_kernel)
-
-            def _stage_merge(idx, s1):
-                return retriever.serve_stage_merge(
-                    cfg, idx, s1, items_per_cluster=items_per_cluster,
-                    use_kernel=use_kernel, fused=fused)
-
-            def _stage_ranking(p, s1, s2, task):
-                return retriever.serve_stage_ranking(p, cfg, s1, s2,
-                                                     task=task)
         self._serve_jit = jax.jit(_serve, static_argnames=("task",))
-        self._stage_rank_jit = jax.jit(_stage_rank,
-                                       static_argnames=("task",))
-        self._stage_merge_jit = jax.jit(_stage_merge)
-        self._stage_ranking_jit = jax.jit(_stage_ranking,
-                                          static_argnames=("task",))
         # shadow-probe pipeline (obs/quality.py): attached by
         # enable_probes(); the oracle user tower is a separate tiny jit
         # so probe re-scoring never touches the serve jits
@@ -338,40 +303,24 @@ class RetrievalService:
         return holder["entry"].version
 
     # -- request path ----------------------------------------------------------
-    def _serve_staged(self, params, state, index, jbatch, task: int,
-                      sink: List[trace_lib.Span]) -> Dict[str, jnp.ndarray]:
-        """Traced serve: three stage jits with a device sync per stage.
-
-        Stage spans carry REAL wall times (the fused jit hides stage
-        boundaries inside XLA); the numerics are identical because the
-        fused path composes the very same stage functions.
-        """
-        t0 = time.monotonic()
-        s1 = jax.block_until_ready(
-            self._stage_rank_jit(params, state, index, jbatch, task=task))
-        t1 = time.monotonic()
-        sink.append(trace_lib.make_span("shard_rank", t0, t1,
-                                        n_shards=self.n_shards or 1))
-        s2 = jax.block_until_ready(self._stage_merge_jit(index, s1))
-        t2 = time.monotonic()
-        sink.append(trace_lib.make_span("merge", t1, t2))
-        out = jax.block_until_ready(
-            self._stage_ranking_jit(params, s1, s2, task=task))
-        sink.append(trace_lib.make_span("ranking", t2))
-        return out
-
     def serve_batch(self, batch: Dict[str, np.ndarray], task: int = 0,
                     n_valid: Optional[int] = None,
-                    span_sink: Optional[List[trace_lib.Span]] = None
+                    span_sink: Optional[List[trace_lib.Span]] = None,
+                    flush: Optional[int] = None
                     ) -> Dict[str, np.ndarray]:
         """Serve one request batch.
 
         ``n_valid`` lets a padding caller (the MicroBatcher) report how
         many leading rows are real so ``stats.n_requests`` stays exact.
-        ``span_sink`` (a list, normally passed by the batcher for traced
-        flushes) selects the staged serve path and receives its per-stage
-        spans; without it, a direct call on a service with a sampling
-        tracer records its own trace.
+        The host phases run in spans (``obs.trace.span``): ``serve.put``
+        (the batch to the device), ``serve.dispatch`` (the jit call,
+        which returns once the program is enqueued) and ``serve.fetch``
+        (the outputs to the host, which waits for the device).  Each
+        carries ``task``, ``rows`` (real rows), ``bucket`` (padded rows)
+        and, from the batcher, ``flush`` (its flush sequence number).
+        ``span_sink`` (a list, passed by the batcher for traced flushes)
+        receives them; without it, a direct call on a service with a
+        sampling tracer records its own trace.
         """
         own_trace = None
         if span_sink is None and self.tracer is not None \
@@ -379,23 +328,25 @@ class RetrievalService:
             own_trace = self.tracer.start_trace(
                 "serve_batch", rows=len(batch["user_id"]), task=task)
             span_sink = []
+        bucket = len(batch["user_id"])
+        args = dict(task=task, rows=bucket if n_valid is None else n_valid,
+                    bucket=bucket)
+        if flush is not None:
+            args["flush"] = flush
         t0 = time.perf_counter()
         with self._lock:
             params, state = self._params, self._index_state
         gen = self._buffer.current()            # atomic epoch-tagged read
-        jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
+        with trace_lib.span("serve.put", span_sink, **args):
+            jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
         t_jit = time.perf_counter()
-        if span_sink is not None:
-            out = self._serve_staged(params, state, gen.index, jbatch,
-                                     task, span_sink)
-            stage_name = "serve_staged"
-        else:
+        with trace_lib.span("serve.dispatch", span_sink, **args):
             out = self._serve_jit(params, state, gen.index, jbatch,
                                   task=task)
-            stage_name = "serve_jit"
-        out = {k: np.asarray(v) for k, v in out.items()}
+        with trace_lib.span("serve.fetch", span_sink, **args):
+            out = {k: np.asarray(v) for k, v in out.items()}
         t1 = time.perf_counter()
-        self.stats.stage(stage_name).record(t1 - t_jit)
+        self.stats.stage("serve_jit").record(t1 - t_jit)
         self.stats.latency.record(t1 - t0)
         # counters mutate under the lock so concurrent callers stay exact
         with self._lock:
@@ -429,7 +380,8 @@ class RetrievalService:
                      max_delay_s: float = 0.002,
                      buckets=None) -> batcher_lib.MicroBatcher:
         """Micro-batching front door sharing this service's telemetry
-        (and tracer: sampled requests get queue-wait + stage spans)."""
+        (and tracer: sampled requests get queue-wait and their flush's
+        batcher.* and serve.* spans)."""
         return batcher_lib.MicroBatcher(
             self.serve_batch, max_batch=max_batch,
             max_delay_s=max_delay_s, buckets=buckets, stats=self.stats,

@@ -184,20 +184,21 @@ def sharded_stage_rank(params: Params, state: IndexState, cfg: SVQConfig,
                        mesh: Optional[Mesh] = None) -> Dict[str, jax.Array]:
     """Stages 1-2: per-shard cluster ranking + cross-shard merge.
 
-    Mirrors ``retriever.serve_stage_rank`` (same output keys), so the
-    observability layer times the sharded and single-device pipelines
-    through one staged interface; ``sharded_serve`` composes the stage
-    functions op-for-op.
+    Mirrors ``retriever.serve_stage_rank`` (same output keys and the same
+    named scopes); ``sharded_serve`` composes the stage functions under
+    one jit.
     """
     D = sidx.n_shards
     ks = sidx.clusters_per_shard
     C = cfg.clusters_per_query
     n_local = min(C, ks)
 
-    user_feat, hist_emb = user_features(params, batch["user_id"],
-                                        batch["hist"])
-    u = jax.vmap(lambda tw: mlp(tw, user_feat))(params["user_towers"])[task]
-    u = constrain(u, mesh, P(SHARD_AXIS, None))
+    with trace.annotate("user_tower"):
+        user_feat, hist_emb = user_features(params, batch["user_id"],
+                                            batch["hist"])
+        u = jax.vmap(lambda tw: mlp(tw, user_feat))(
+            params["user_towers"])[task]
+        u = constrain(u, mesh, P(SHARD_AXIS, None))
 
     # ---- stage 1: per-shard indexing step (local cluster ranking) ------
     e_all = state.vq.embeddings()
@@ -257,14 +258,16 @@ def sharded_stage_merge(cfg: SVQConfig, sidx: ShardedServingIndex,
     L = items_per_cluster
     top_scores, top_clusters = s1["top_scores"], s1["top_clusters"]
 
-    # ---- stage 3: routed slab fetch from the owning shards -------------
-    owner = top_clusters // ks                                   # (B, C)
-    local_c = top_clusters % ks
-    lstart = sidx.offsets[owner, local_c]
-    counts = sidx.counts[owner, local_c]      # live prefix (tombstone-aware)
-    ar = jnp.arange(L, dtype=jnp.int32)
-    lengths = jnp.minimum(counts, L)
     S = cfg.candidates_out
+
+    # ---- stage 3: routed slab fetch from the owning shards -------------
+    with trace.annotate("slab_gather"):
+        owner = top_clusters // ks                               # (B, C)
+        local_c = top_clusters % ks
+        lstart = sidx.offsets[owner, local_c]
+        counts = sidx.counts[owner, local_c]  # live prefix (tombstones)
+        ar = jnp.arange(L, dtype=jnp.int32)
+        lengths = jnp.minimum(counts, L)
 
     if fused:
         # flattened (D * cap) addressing: min(owner*cap + local + i,
@@ -277,53 +280,57 @@ def sharded_stage_merge(cfg: SVQConfig, sidx: ShardedServingIndex,
                 sidx.item_bias.reshape(-1), sidx.item_ids.reshape(-1),
                 sidx.item_emb.reshape(-1, sidx.item_emb.shape[-1]),
                 cfg.chunk_size, S, L, use_kernel=use_kernel)
-        valid = pos >= 0
-        c_idx = jnp.clip(pos, 0) // L
-        i_idx = jnp.clip(pos, 0) % L
-        owner_s = jnp.take_along_axis(owner, c_idx, axis=1)
-        lstart_s = jnp.take_along_axis(lstart, c_idx, axis=1)
-        flat = jnp.minimum(sidx.item_base[owner_s] + lstart_s + i_idx,
-                           sidx.n_items - 1)
-        cand_ids = _route_candidate_ids(sidx, flat, D, cap)
+        with trace.annotate("cand_gather"):
+            valid = pos >= 0
+            c_idx = jnp.clip(pos, 0) // L
+            i_idx = jnp.clip(pos, 0) % L
+            owner_s = jnp.take_along_axis(owner, c_idx, axis=1)
+            lstart_s = jnp.take_along_axis(lstart, c_idx, axis=1)
+            flat = jnp.minimum(sidx.item_base[owner_s] + lstart_s + i_idx,
+                               sidx.n_items - 1)
+            cand_ids = _route_candidate_ids(sidx, flat, D, cap)
         return dict(cand_ids=cand_ids, valid=valid,
                     merge_scores=msort_scores, exact_scores=exact_scores)
 
-    # global flat positions, identical (incl. the n-1 clamp) to the
-    # single-device ``starts[..., None] + arange`` slab
-    slab = jnp.minimum(sidx.item_base[owner][..., None]
-                       + lstart[..., None] + ar, sidx.n_items - 1)
-    # bias values come from the owning shard's local arrays; lanes past
-    # ``lengths`` are padding garbage in BOTH paths and both merge
-    # implementations mask them, so outputs stay bit-exact
-    lslab = jnp.minimum(lstart[..., None] + ar, cap - 1)
-    bias = sidx.item_bias[owner[..., None], lslab]               # (B, C, L)
-    bias = constrain(bias, mesh, P(SHARD_AXIS, None, None))
+    with trace.annotate("slab_gather"):
+        # global flat positions, identical (incl. the n-1 clamp) to the
+        # single-device ``starts[..., None] + arange`` slab
+        slab = jnp.minimum(sidx.item_base[owner][..., None]
+                           + lstart[..., None] + ar, sidx.n_items - 1)
+        # bias values come from the owning shard's local arrays; lanes
+        # past ``lengths`` are padding garbage in BOTH paths and both
+        # merge implementations mask them, so outputs stay bit-exact
+        lslab = jnp.minimum(lstart[..., None] + ar, cap - 1)
+        bias = sidx.item_bias[owner[..., None], lslab]           # (B, C, L)
+        bias = constrain(bias, mesh, P(SHARD_AXIS, None, None))
 
     # ---- stage 4a: Alg. 1 merge (batch-parallel) -----------------------
     with trace.annotate("merge_serve"):
         pos, msort_scores = serve_kernel(top_scores, bias, lengths,
                                          cfg.chunk_size, S,
                                          use_kernel=use_kernel)
-    valid = pos >= 0
-    c_idx = jnp.clip(pos, 0) // L
-    i_idx = jnp.clip(pos, 0) % L
-    flat = jnp.take_along_axis(
-        slab.reshape(slab.shape[0], -1),
-        (c_idx * L + i_idx).astype(jnp.int32), axis=1)           # (B, S)
+    with trace.annotate("cand_gather"):
+        valid = pos >= 0
+        c_idx = jnp.clip(pos, 0) // L
+        i_idx = jnp.clip(pos, 0) % L
+        flat = jnp.take_along_axis(
+            slab.reshape(slab.shape[0], -1),
+            (c_idx * L + i_idx).astype(jnp.int32), axis=1)       # (B, S)
 
-    cand_ids = _route_candidate_ids(sidx, flat, D, cap)
-    # exact Eq. 11 candidate score from the sharded payload — what the
-    # fused path computes in-kernel
-    fowner = jnp.clip(
-        jnp.searchsorted(sidx.item_base, flat, side="right") - 1, 0, D - 1)
-    flocal = jnp.clip(flat - sidx.item_base[fowner], 0, cap - 1)
-    exact_scores = jnp.where(
-        valid,
-        jnp.einsum("bsd,bd->bs",
-                   sidx.item_emb[fowner, flocal].astype(jnp.float32),
-                   s1["u"].astype(jnp.float32))
-        + sidx.item_bias[fowner, flocal].astype(jnp.float32),
-        merge_sort.NEG)
+        cand_ids = _route_candidate_ids(sidx, flat, D, cap)
+        # exact Eq. 11 candidate score from the sharded payload — what
+        # the fused path computes in-kernel
+        fowner = jnp.clip(
+            jnp.searchsorted(sidx.item_base, flat, side="right") - 1,
+            0, D - 1)
+        flocal = jnp.clip(flat - sidx.item_base[fowner], 0, cap - 1)
+        exact_scores = jnp.where(
+            valid,
+            jnp.einsum("bsd,bd->bs",
+                       sidx.item_emb[fowner, flocal].astype(jnp.float32),
+                       s1["u"].astype(jnp.float32))
+            + sidx.item_bias[fowner, flocal].astype(jnp.float32),
+            merge_sort.NEG)
     return dict(cand_ids=cand_ids, valid=valid,
                 merge_scores=msort_scores, exact_scores=exact_scores)
 
@@ -370,22 +377,24 @@ def sharded_stage_ranking(params: Params, cfg: SVQConfig,
     cand_ids = constrain(cand_ids, mesh, batch_spec)
     user_feat = constrain(s1["user_feat"], mesh, batch_spec)
     hist_emb = constrain(s1["hist_emb"], mesh, batch_spec)
-    cand_cate = jnp.zeros_like(cand_ids)
-    item_feat = item_features(params, cand_ids, cand_cate)
-    cross = (item_feat[..., :cfg.item_embed_dim]
-             * user_feat[..., None, -cfg.item_embed_dim:])
-    rscores = ranking.ranking_scores(params["rank"], cfg, user_feat,
-                                     item_feat, hist_emb, cross)[task]
-    rscores = constrain(rscores, mesh, batch_spec)
-    rscores = jnp.where(valid, rscores, merge_sort.NEG)
-    order = jnp.argsort(-rscores, axis=-1)
-    return dict(
-        item_ids=jnp.take_along_axis(cand_ids, order, axis=1),
-        scores=jnp.take_along_axis(rscores, order, axis=1),
-        merge_scores=s2["merge_scores"],
-        exact_scores=s2["exact_scores"],
-        index_ids=cand_ids,
-        valid=jnp.take_along_axis(valid, order, axis=1))
+    with trace.annotate("rank_features"):
+        cand_cate = jnp.zeros_like(cand_ids)
+        item_feat = item_features(params, cand_ids, cand_cate)
+        cross = (item_feat[..., :cfg.item_embed_dim]
+                 * user_feat[..., None, -cfg.item_embed_dim:])
+    with trace.annotate("rank_score"):
+        rscores = ranking.ranking_scores(params["rank"], cfg, user_feat,
+                                         item_feat, hist_emb, cross)[task]
+        rscores = constrain(rscores, mesh, batch_spec)
+        rscores = jnp.where(valid, rscores, merge_sort.NEG)
+        order = jnp.argsort(-rscores, axis=-1)
+        return dict(
+            item_ids=jnp.take_along_axis(cand_ids, order, axis=1),
+            scores=jnp.take_along_axis(rscores, order, axis=1),
+            merge_scores=s2["merge_scores"],
+            exact_scores=s2["exact_scores"],
+            index_ids=cand_ids,
+            valid=jnp.take_along_axis(valid, order, axis=1))
 
 
 def sharded_serve(params: Params, state: IndexState, cfg: SVQConfig,
