@@ -11,8 +11,11 @@ k-way merge.  Alg. 1 pops the max-head cluster and takes a whole CHUNK
 TPU adaptation (DESIGN.md §3): a binary heap is pointer-chasing and
 serial; but a heap-pop is just argmax over the C head scores (C =
 clusters_per_query, e.g. 128).  We implement Alg. 1 as a lax.scan of S/l
-steps, each doing an argmax over C running heads -- bit-identical pop
-order to the heap under distinct scores, fully vectorizable and vmappable
+steps, each doing an argmax over C head scores carried in the scan's
+state: a pop reads its chunk and the popped cluster's next head, and
+refreshes that one head, so no step re-reads the other C - 1 heads.
+Bit-identical pop order to the heap (ties go to the lowest cluster, as
+the heap's (-score, cluster) order), fully vectorizable and vmappable
 over queries.  A numpy heapq oracle is kept for verification and the
 merge-sort benchmark.
 """
@@ -78,6 +81,12 @@ def merge_sort_serve(cluster_scores: jax.Array,
     with (-1, NEG) if fewer than ``target`` items exist.  vmap over the
     leading axis for batched queries.
 
+    The scan carries each cluster's pointer ``ptr`` and head bias
+    ``head_b = bias_lists[c, min(ptr[c], L - 1)]``.  A pop of cluster
+    ``c`` reads its chunk and its next head from row ``c`` of
+    ``bias_lists`` and refreshes ``head_b[c]`` alone: per pop the slab is
+    read at ``chunk + 1`` places, not at every cluster's head.
+
     ``exact=True`` budgets ceil(target/chunk) + C pops (each pop either
     yields a full chunk or exhausts one of the C clusters, so this bound
     guarantees heap-oracle-identical output); ``exact=False`` budgets only
@@ -86,30 +95,30 @@ def merge_sort_serve(cluster_scores: jax.Array,
     """
     C, L = bias_lists.shape
     n_steps = -(-target // chunk) + (C if exact else 0)
-    arange_chunk = jnp.arange(chunk)
-
-    def head_score(ptr):
-        b = jnp.take_along_axis(
-            bias_lists, jnp.minimum(ptr, L - 1)[:, None], axis=1)[:, 0]
-        s = cluster_scores + b
-        return jnp.where(ptr < lengths, s, NEG)
+    offsets = jnp.arange(chunk + 1)
+    iota_c = jnp.arange(C)
 
     def step(carry, _):
-        ptr, n_out = carry
-        scores = head_score(ptr)
+        ptr, head_b, n_out = carry
+        scores = jnp.where(ptr < lengths, cluster_scores + head_b, NEG)
         c = jnp.argmax(scores)
+        hit = iota_c == c
         base = ptr[c]
-        idx = base + arange_chunk
+        # the chunk and, in its last slot, the popped cluster's next head
+        idx = base + offsets
+        vals = bias_lists[c, jnp.minimum(idx, L - 1)]
+        idx = idx[:chunk]
         valid = ((idx < lengths[c]) & (scores[c] > NEG / 2)
                  & (n_out < target))
         pos = jnp.where(valid, c * L + idx, -1)
-        sc = jnp.where(valid, cluster_scores[c] + bias_lists[c, :][
-            jnp.minimum(idx, L - 1)], NEG)
-        return (ptr.at[c].add(chunk), n_out + jnp.sum(valid)), (pos, sc)
+        sc = jnp.where(valid, cluster_scores[c] + vals[:chunk], NEG)
+        ptr = jnp.where(hit, ptr + chunk, ptr)
+        head_b = jnp.where(hit, vals[chunk], head_b)
+        return (ptr, head_b, n_out + jnp.sum(valid)), (pos, sc)
 
     ptr0 = jnp.zeros((C,), jnp.int32)
-    _, (pos, sc) = jax.lax.scan(step, (ptr0, jnp.int32(0)), None,
-                                length=n_steps)
+    _, (pos, sc) = jax.lax.scan(step, (ptr0, bias_lists[:, 0], jnp.int32(0)),
+                                None, length=n_steps)
     pos, sc = pos.reshape(-1), sc.reshape(-1)
     # Compact valid entries forward, preserving pop order (matches the
     # heap oracle's contiguous output even when chunks were partial).

@@ -6,6 +6,9 @@ Pallas kernel (`ops.merge_serve`, interpret mode), plus cluster_rank
 against `lax.top_k(u @ e.T, n)` and the `retriever.serve_kernel`
 dispatch equivalence.
 """
+import re
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,6 +125,147 @@ def test_inexact_budget_subset_of_exact(rng):
     n_i = int((got_i >= 0).sum())
     np.testing.assert_array_equal(got_i[:n_i], got_e[:n_i])
     assert n_i <= int((got_e >= 0).sum())
+
+
+def _assert_lax_batch_bit_exact(cs, bl, ln, chunk, target):
+    """Batched lax merge (``ref.merge_serve_ref``) == heap oracle per
+    query, positions and scores bit for bit, padding included."""
+    pos_b, sc_b = ref.merge_serve_ref(jnp.asarray(cs), jnp.asarray(bl),
+                                      jnp.asarray(ln), chunk, target)
+    pos_b, sc_b = np.asarray(pos_b), np.asarray(sc_b)
+    for b in range(cs.shape[0]):
+        pos_np, sc_np = merge_sort.merge_sort_serve_np(cs[b], bl[b], ln[b],
+                                                       chunk, target)
+        n = len(pos_np)
+        np.testing.assert_array_equal(pos_b[b, :n], pos_np, err_msg=b)
+        np.testing.assert_array_equal(sc_b[b, :n].astype(np.float64),
+                                      sc_np, err_msg=b)
+        assert np.all(pos_b[b, n:] == -1), b
+        assert np.all(sc_b[b, n:] == np.float32(merge_sort.NEG)), b
+
+
+def _sorted_desc(x):
+    return -np.sort(-x, axis=-1)
+
+
+def _case_dry_mid_merge(rng):
+    """64 queries whose short clusters run dry while the merge still
+    has items to take: exhausted heads must leave the argmax."""
+    B, C, L, chunk = 64, 16, 32, 4
+    cs = rng.normal(size=(B, C)).astype(np.float32)
+    bl = _sorted_desc(rng.normal(size=(B, C, L)).astype(np.float32))
+    ln = rng.integers(0, 13, size=(B, C)).astype(np.int32)
+    return cs, bl, ln, chunk, 64
+
+
+def _case_edge_lengths(rng):
+    """Lengths 0, 1, chunk - 1, chunk and L (the cap), in every query."""
+    B, C, L, chunk = 16, 10, 24, 5
+    cs = rng.normal(size=(B, C)).astype(np.float32)
+    bl = _sorted_desc(rng.normal(size=(B, C, L)).astype(np.float32))
+    edges = np.array([0, 1, chunk - 1, chunk, L], np.int32)
+    ln = np.stack([rng.permutation(np.resize(edges, C))
+                   for _ in range(B)]).astype(np.int32)
+    return cs, bl, ln, chunk, C * L
+
+
+def _case_tied_heads(rng):
+    """Every cluster's head score equal: the lowest cluster wins each
+    tie, as in the heap's (-score, cluster) order."""
+    B, C, L, chunk = 16, 12, 16, 4
+    cs = np.full((B, C), 0.5, np.float32)
+    bl = _sorted_desc(rng.integers(0, 3, size=(B, C, L)).astype(np.float32))
+    bl[:, :, 0] = 3.0
+    ln = rng.integers(1, L + 1, size=(B, C)).astype(np.int32)
+    return cs, bl, ln, chunk, 96
+
+
+def _case_repop_after_overtake(rng):
+    """Chunk k of cluster c scores -(k * C + rank(c)): each cluster's
+    head is overtaken by every other cluster's after each pop, so pops
+    go round robin and every cluster is popped again later."""
+    B, C, L, chunk = 8, 6, 24, 4
+    rank = np.stack([rng.permutation(C) for _ in range(B)])
+    k = np.arange(L) // chunk
+    bl = -(k[None, None, :] * C + rank[:, :, None]).astype(np.float32)
+    cs = np.zeros((B, C), np.float32)
+    ln = np.full((B, C), L, np.int32)
+    target = 3 * C * chunk
+    pos_np, _ = merge_sort.merge_sort_serve_np(cs[0], bl[0], ln[0], chunk,
+                                               target)
+    popped = pos_np[::chunk] // L
+    np.testing.assert_array_equal(popped, np.tile(np.argsort(rank[0]), 3))
+    return cs, bl, ln, chunk, target
+
+
+@pytest.mark.parametrize("case", [_case_dry_mid_merge, _case_edge_lengths,
+                                  _case_tied_heads,
+                                  _case_repop_after_overtake],
+                         ids=lambda f: f.__name__[len("_case_"):])
+def test_carried_heads_match_heap_oracle(rng, case):
+    _assert_lax_batch_bit_exact(*case(rng))
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_HLO_CALLEE = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)")
+_HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_HLO_GATHER = re.compile(
+    r"= \w+\[([\d,]*)\]\S* gather\(.*slice_sizes=\{([\d,]*)\}")
+
+
+def _while_body_gathers(hlo: str):
+    """(n_slices, slice_elems) of every gather that a ``while`` body of
+    the compiled module ``hlo`` reaches, fusions and calls included."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    todo = [b for lines in comps.values() for line in lines
+            if " while(" in line
+            for b in re.findall(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += _HLO_CALLEE.findall(line)
+            for group in _HLO_BRANCHES.findall(line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    gathers = []
+    for c in seen:
+        for line in comps[c]:
+            m = _HLO_GATHER.search(line)
+            if m:
+                out = int(np.prod([int(d) for d in m.group(1).split(",")]))
+                win = int(np.prod([int(d) for d in m.group(2).split(",")]))
+                gathers.append((out // win, win))
+    return gathers
+
+
+def test_merge_loop_gathers_no_per_cluster_heads():
+    """At the serve cells' shapes, no pop re-gathers every cluster's
+    head: the compiled loop body holds no gather of B x C or more
+    single-element slices (the carried heads make it chunk + 1 per
+    query)."""
+    B, C, L = 512, 128, 256
+    f = jax.jit(partial(ref.merge_serve_ref, chunk=8, target=512,
+                        exact=True))
+    hlo = f.lower(jax.ShapeDtypeStruct((B, C), jnp.float32),
+                  jax.ShapeDtypeStruct((B, C, L), jnp.float32),
+                  jax.ShapeDtypeStruct((B, C), jnp.int32)
+                  ).compile().as_text()
+    gathers = _while_body_gathers(hlo)
+    assert gathers, "no gather found in the merge's while body"
+    scalar = [n for n, win in gathers if win == 1]
+    assert max(scalar) < B * C, gathers
 
 
 # ---------------------------------------------------------------------------
