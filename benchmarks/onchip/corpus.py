@@ -5,9 +5,10 @@ plain reference (``reference.py``) can read them without taking anything
 the program produced.  One jitted call per seed makes:
 
 - the retriever's parameters in the program's own layout (embedding
-  tables, item tower, one user tower per task, ranking towers), drawn as
-  ``retriever.init`` draws them: normal weights scaled by fan-in, zero
-  biases, tables scaled by their width;
+  tables, item tower, one user tower per task, and the ranking model's
+  weights from ``models/<ranking>.py``), drawn as ``retriever.init``
+  draws them: normal weights scaled by fan-in, zero biases, tables scaled
+  by their width;
 - the corpus: every item id that owns its assignment-store slot alone
   (the store hashes ids into ``n_items`` slots, so only these can be held
   at once), its category, its item-tower embedding and popularity bias;
@@ -21,11 +22,14 @@ store, from which ``RetrievalService`` builds its serving index.
 from __future__ import annotations
 
 import dataclasses
+from pathlib import Path
 from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import rankers
 
 N_CATES = 4096            # rows of the program's item_cate table
 ASSIGN_CHUNK = 4096       # corpus rows per nearest-cluster block
@@ -64,7 +68,9 @@ class Corpus(NamedTuple):
     cluster: jax.Array      # (N,) int32 nearest cluster of each item
 
 
-def _mlp_params(key, d_in, dims):
+def mlp_params(key, d_in, dims):
+    """Dense layers d_in -> dims: normal weights scaled by fan-in, zero
+    biases."""
     layers = []
     for k, h in zip(jax.random.split(key, len(dims)), dims):
         layers.append({"w": jax.random.normal(k, (d_in, h), jnp.float32)
@@ -74,7 +80,8 @@ def _mlp_params(key, d_in, dims):
     return {"layers": layers}
 
 
-def _stacked(key, n, fn):
+def stacked(key, n, fn):
+    """``fn(key)`` for each of n keys split from ``key``, stacked."""
     return jax.vmap(fn)(jax.random.split(key, n))
 
 
@@ -87,11 +94,10 @@ def _mlp(p, x):
     return x
 
 
-def make_params(key: jax.Array, cfg) -> Dict:
+def make_params(key: jax.Array, cfg, here: Path = rankers.HERE) -> Dict:
     """The retriever's parameters, in the layout ``retriever.serve``
-    reads (two-tower ranking)."""
-    if cfg.ranking != "two_tower":
-        raise ValueError(f"ranking {cfg.ranking!r} has no benchmark maker")
+    reads; ``"rank"`` from the configuration's ranking model
+    (``models/<cfg.ranking>.py`` under ``here``)."""
     kt, ki, ku, kr = jax.random.split(key, 4)
     d_user_in = cfg.user_embed_dim + cfg.item_embed_dim
     d_item_in = 2 * cfg.item_embed_dim
@@ -103,20 +109,14 @@ def make_params(key: jax.Array, cfg) -> Dict:
              ("item_cate", N_CATES, cfg.item_embed_dim))):
         tables[name] = jax.random.normal(k, (rows, dim), jnp.float32) \
             * dim ** -0.5
-    kru, kri = jax.random.split(kr)
-    rank_item_dims = cfg.ranking_mlp[:-1] + (cfg.ranking_mlp[-1] + 1,)
     return {
         "tables": tables,
-        "item_tower": _mlp_params(
+        "item_tower": mlp_params(
             ki, d_item_in, cfg.item_tower[:-1] + (cfg.embed_dim + 1,)),
-        "user_towers": _stacked(ku, cfg.n_tasks, lambda k: _mlp_params(
+        "user_towers": stacked(ku, cfg.n_tasks, lambda k: mlp_params(
             k, d_user_in, cfg.user_tower[:-1] + (cfg.embed_dim,))),
-        "rank": {
-            "user_mlp": _stacked(kru, cfg.n_tasks, lambda k: _mlp_params(
-                k, d_user_in, cfg.ranking_mlp)),
-            "item_mlp": _stacked(kri, cfg.n_tasks, lambda k: _mlp_params(
-                k, d_item_in, rank_item_dims)),
-        },
+        "rank": rankers.ranking_model(cfg.ranking, here).make_rank_params(
+            kr, cfg),
     }
 
 

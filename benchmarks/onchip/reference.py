@@ -12,13 +12,15 @@ the program:
 3. Alg. 1: a binary heap over those clusters' item lists (bias
    descending, ties by store slot, at most ``items_per_cluster`` each),
    popping ``chunk_size`` items at a time up to ``candidates_out``;
-4. exact score ``u . v + b`` and the two-tower ranking score of each
-   candidate.
+4. exact score ``u . v + b`` of each candidate, and its score by the
+   configuration's ranking model (``models/<ranking>.py``, found by
+   ``rankers.ranking_model``), which reads the user feature, the
+   history's rows and the candidates' rows.
 
 ``precision="highest"`` is the reference.  ``precision="high"`` is the
-control that has to fail: every product in three bfloat16 passes
-(``_mm``), what the TPU's "high" precision does, one step below the
-float32 the configurations state.
+control that has to fail: every product, the ranking model's too, in
+three bfloat16 passes (``plain.mm``), what the TPU's "high" precision
+does, one step below the float32 the configurations state.
 
 ``compare`` holds what the program served against the reference's own
 serve of the same requests, request by request, and returns the numbers
@@ -28,12 +30,15 @@ from __future__ import annotations
 
 import functools
 import heapq
+from pathlib import Path
 from typing import Dict, List, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import plain
+import rankers
 from corpus import N_CATES, hash_ids_np
 
 NEG = -1e30
@@ -101,53 +106,27 @@ def heap_merge(scores: np.ndarray, clusters: np.ndarray, lists: Lists,
     return np.asarray(rows, np.int64), np.asarray(merged, np.float64)
 
 
-def _bf16(x):
-    """x rounded to bfloat16, kept in float32 (``reduce_precision`` is not
-    folded away under jit, as a float32 -> bfloat16 -> float32 round trip
-    may be)."""
-    return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
-
-
-def _mm(spec: str, a, b, precision: str):
-    """einsum at ``precision``: "highest" is float32; "high" is the TPU's
-    three-pass bfloat16 product, emulated alike on every backend: each
-    operand split into a bfloat16 head and tail, and head*head +
-    head*tail + tail*head summed in float32."""
-    if precision == "highest":
-        return jnp.einsum(spec, a, b, precision="highest")
-    if precision != "high":
-        raise ValueError(f"unknown precision {precision!r}")
-    ah, bh = _bf16(a), _bf16(b)
-    al, bl = _bf16(a - ah), _bf16(b - bh)
-    mm = lambda x, y: jnp.einsum(spec, x, y, precision="highest")
-    return mm(ah, bh) + (mm(ah, bl) + mm(al, bh))
-
-
-def _mlp(p, x, precision):
-    n = len(p["layers"])
-    for i, lp in enumerate(p["layers"]):
-        x = _mm("...i,io->...o", x, lp["w"], precision) + lp["b"]
-        if i < n - 1:
-            x = jax.nn.relu(x)
-    return x
-
-
-def _task(tree, t):
-    return jax.tree_util.tree_map(lambda a: a[t], tree)
+class User(NamedTuple):
+    """M requests' user side, for one task."""
+    feat: jax.Array         # (M, d_user_in) user feature
+    vec: jax.Array          # (M, d) the task's user vector
+    hist_rows: np.ndarray   # (M, H) item-table rows of the history
 
 
 class Reference:
     """The reference (or, at "high", the control) over one corpus."""
 
     def __init__(self, cfg, params, corpus, precision: str = "highest",
-                 items_per_cluster: int = 256):
+                 items_per_cluster: int = 256, here: Path = rankers.HERE):
         self.cfg = cfg
         self.precision = precision
         self.cap = items_per_cluster
         self.params = params
+        self.rank = rankers.ranking_model(cfg.ranking, here).reference_rank
         self.codebook = corpus.codebook
         self.emb = corpus.emb
         self.ids = np.asarray(corpus.ids)
+        self.cate = np.asarray(corpus.cate)
         self.cluster = np.asarray(corpus.cluster)
         self.bias = np.asarray(corpus.bias, np.float32)
         self.lists = build_lists(self.cluster, self.bias, self.ids,
@@ -167,24 +146,28 @@ class Reference:
     def _add(a, b):
         return np.float32(np.float32(a) + np.float32(b))
 
-    def user(self, user_id: np.ndarray, hist: np.ndarray, task: int):
-        """(user feature, user vector) of every request, for one task."""
-        return _user(self.params, hash_ids_np(user_id, self.cfg.n_users),
-                     hash_ids_np(hist, self.cfg.n_items), task,
-                     self.precision)
+    def user(self, user_id: np.ndarray, hist: np.ndarray,
+             task: int) -> User:
+        """Every request's user side, for one task."""
+        hist_rows = hash_ids_np(hist, self.cfg.n_items)
+        uf, u = _user(self.params, hash_ids_np(user_id, self.cfg.n_users),
+                      hist_rows, task, self.precision)
+        return User(feat=uf, vec=u, hist_rows=hist_rows)
 
     def cluster_scores(self, u):
-        return _mm("bd,kd->bk", u, self.codebook, self.precision)
+        return plain.mm("bd,kd->bk", u, self.codebook, self.precision)
 
-    def score_items(self, uf, u, item_ids: np.ndarray, task: int):
+    def score_items(self, user: User, item_ids: np.ndarray, task: int):
         """(exact u.v + b, ranking score) of each candidate id; ids
         outside the corpus get NEG."""
         rows = self.rows_of(item_ids)
+        safe = np.maximum(rows, 0)
+        feats = dict(uf=user.feat, hist_rows=user.hist_rows, rows=safe,
+                     id_rows=hash_ids_np(item_ids, self.cfg.n_items),
+                     cate_rows=hash_ids_np(self.cate[safe], N_CATES))
         exact, rank = _score(self.params, self.emb, jnp.asarray(self.bias),
-                             uf, u, np.maximum(rows, 0),
-                             hash_ids_np(item_ids, self.cfg.n_items),
-                             int(hash_ids_np(np.zeros(1), N_CATES)[0]),
-                             task, self.precision)
+                             user.vec, feats, self.rank, task,
+                             self.precision)
         ok = rows >= 0
         return (np.where(ok, np.asarray(exact, np.float64), NEG),
                 np.where(ok, np.asarray(rank, np.float64), NEG))
@@ -199,8 +182,8 @@ class Reference:
         ranked = np.full((m, s), NEG)
         for task in np.unique(tasks):
             sel = tasks == task
-            uf, u = self.user(user_id, hist, int(task))
-            top_s, top_c = jax.lax.top_k(self.cluster_scores(u),
+            user = self.user(user_id, hist, int(task))
+            top_s, top_c = jax.lax.top_k(self.cluster_scores(user.vec),
                                          cfg.clusters_per_query)
             top_s, top_c = np.asarray(top_s), np.asarray(top_c)
             for q in np.flatnonzero(sel):
@@ -209,7 +192,7 @@ class Reference:
                                       s, self._add)
                 ids[q, :rows.size] = self.ids[rows]
                 merge[q, :rows.size] = sc
-            ex, rk = self.score_items(uf, u, np.maximum(ids, 0), int(task))
+            ex, rk = self.score_items(user, np.maximum(ids, 0), int(task))
             valid = sel[:, None] & (ids >= 0)
             exact = np.where(valid, ex, exact)
             ranked = np.where(valid, rk, ranked)
@@ -225,24 +208,15 @@ def _user(params, uid_rows, hist_rows, task, precision):
     t = params["tables"]
     uf = jnp.concatenate([t["user_id"][uid_rows],
                           jnp.mean(t["item_id"][hist_rows], axis=-2)], -1)
-    return uf, _mlp(_task(params["user_towers"], task), uf, precision)
+    return uf, plain.mlp(plain.task_slice(params["user_towers"], task), uf,
+                         precision)
 
 
-@functools.partial(jax.jit, static_argnames=("task", "precision"))
-def _score(params, emb, bias, uf, u, rows, id_rows, cate_row, task,
-           precision):
-    exact = _mm("bsd,bd->bs", emb[rows], u, precision) + bias[rows]
-    t = params["tables"]
-    item_feat = jnp.concatenate([
-        t["item_id"][id_rows],
-        jnp.broadcast_to(t["item_cate"][cate_row],
-                         id_rows.shape + (t["item_cate"].shape[1],))], -1)
-    ru = _mlp(_task(params["rank"]["user_mlp"], task), uf, precision)
-    rv_all = _mlp(_task(params["rank"]["item_mlp"], task), item_feat,
-                  precision)
-    rank = _mm("bd,bsd->bs", ru, rv_all[..., :-1], precision) \
-        + rv_all[..., -1]
-    return exact, rank
+@functools.partial(jax.jit, static_argnames=("rank", "task", "precision"))
+def _score(params, emb, bias, u, feats, rank, task, precision):
+    rows = feats["rows"]
+    exact = plain.mm("bsd,bd->bs", emb[rows], u, precision) + bias[rows]
+    return exact, rank(params, feats, task, precision)
 
 
 def served_from_outputs(outs: List[Dict[str, np.ndarray]]) -> Served:
@@ -313,10 +287,10 @@ def compare(ref: Reference, user_id, hist, tasks, got: Served
             np.sort(ranked), np.sort(got.ids[q][v])))
     nums["cand_miss"] = missed / max(int((own.ids >= 0).sum()), 1)
     for task in np.unique(tasks):
-        uf, u = ref.user(user_id, hist, int(task))
-        cs = np.asarray(ref.cluster_scores(u), np.float64)
-        ex_m, _ = ref.score_items(uf, u, np.maximum(got.ids, 0), int(task))
-        _, rk_r = ref.score_items(uf, u, np.maximum(got.ranked_ids, 0),
+        user = ref.user(user_id, hist, int(task))
+        cs = np.asarray(ref.cluster_scores(user.vec), np.float64)
+        ex_m, _ = ref.score_items(user, np.maximum(got.ids, 0), int(task))
+        _, rk_r = ref.score_items(user, np.maximum(got.ranked_ids, 0),
                                   int(task))
         for q in np.flatnonzero(tasks == task):
             v = got.merge[q] > NEG / 2

@@ -10,6 +10,7 @@ import pytest
 
 import _paths  # noqa: F401
 import control
+import corpus
 import reference
 import run
 from repro.configs.svq import CONFIG
@@ -148,6 +149,33 @@ def test_a_broken_answer_is_not_correct(monkeypatch, fault):
         assert {"count_diff", "cand_miss"} <= failed
     elif fault is _reversed_heap:
         assert failed == {"cand_miss"}
+
+
+# A fingerprint of the weights at SMALL from seed 2**33 + 1, taken before
+# the ranking towers' weights moved into models/two_tower.py: over the
+# leaves of params["rank"] and of the whole tree, flattened in order,
+# (element count, float64 sum, {flat index: value}).
+PINNED = {
+    "rank": (427905, -28.311161270982257,
+             {1000: -0.0422891341149807, 12345: 0.1390955150127411,
+              213952: -0.08973265439271927,
+              427904: -0.0017671141540631652}),
+    "all": (1904386, -147.8326998799551,
+            {1000: -0.013625750318169594, 12345: 0.13305427134037018,
+             952193: 0.24422898888587952, 1904385: 0.011851324699819088}),
+}
+
+
+def test_the_weights_are_pinned():
+    params, _ = corpus.make_all(2 ** 33 + 1, SMALL)
+    for name, tree in (("rank", params["rank"]), ("all", params)):
+        flat = np.concatenate([np.asarray(x).ravel() for x in
+                               jax.tree_util.tree_leaves(tree)])
+        n, total, values = PINNED[name]
+        assert flat.size == n, name
+        assert float(flat.astype(np.float64).sum()) == total, name
+        for i, v in values.items():
+            assert float(flat[i]) == v, (name, i)
 
 
 def test_the_control_fails_a_limit():

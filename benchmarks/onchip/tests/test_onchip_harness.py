@@ -1,13 +1,20 @@
 """The harness finds every cell, configuration, mix and metric by name,
 and the manifest keeps to the benchmark's contract."""
+import dataclasses
 import json
 import re
 import shutil
 
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import _paths  # noqa: F401
+import corpus
+import rankers
+import reference
 import run
+import work
 from repro.configs import svq
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -57,7 +64,10 @@ def test_every_cell_reports_setup_another_e2e_and_a_layer():
 
 def test_everything_named_is_found():
     for c in MAN["configs"]:
-        run.load_config(run.ROOT / c["file"])
+        model = rankers.ranking_model(
+            run.load_config(run.ROOT / c["file"]).ranking)
+        for fn in ("make_rank_params", "reference_rank", "rank_flops"):
+            assert callable(getattr(model, fn)), (c["name"], fn)
     for w in MAN["workloads"]:
         mix = run.load_traffic(w["traffic"])
         assert mix["kind"] == "serve_open_loop"
@@ -71,11 +81,17 @@ def test_config_files_state_the_repo_settings():
         == svq.MULTITASK
 
 
-def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
-    """A later PR adds files and manifest entries only."""
+def _copy_harness(tmp_path):
+    """The benchmark's files, tests left out, under ``tmp_path``."""
     here = tmp_path / "benchmarks" / "onchip"
     shutil.copytree(run.HERE, here, ignore=shutil.ignore_patterns(
         "__pycache__", "tests"))
+    return here
+
+
+def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
+    """A later PR adds files and manifest entries only."""
+    here = _copy_harness(tmp_path)
     cfg = json.loads((here / "configs" / "svq16k.json").read_text())
     cfg["n_clusters"] = 8192
     (here / "configs" / "svq8k.json").write_text(json.dumps(cfg))
@@ -104,6 +120,78 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
     layer = run.metrics_of(m2, "svq8k-slow", "per_layer")
     assert [m["name"] for m in layer] == ["answer.tail"]
     assert run.metric_reader("answer.tail", here)({"x": 21}) == 42
+
+
+TOY_MODEL = '''"""A ranker for tests: a per-task scale times the sum of the
+candidate's item-table row, plus the sum of the history's rows."""
+import jax
+import jax.numpy as jnp
+
+
+def make_rank_params(key, cfg):
+    return {"scale": jax.random.normal(key, (cfg.n_tasks,))}
+
+
+def reference_rank(params, feats, task, precision):
+    t = params["tables"]["item_id"]
+    hist = jnp.sum(t[feats["hist_rows"]], axis=(-2, -1))
+    return params["rank"]["scale"][task] * jnp.sum(t[feats["id_rows"]], -1) \\
+        + hist[:, None]
+
+
+def rank_flops(cfg):
+    return 7
+'''
+
+
+def test_a_new_ranking_model_is_found_by_name(tmp_path):
+    """A configuration with another ``ranking`` needs a model file and a
+    configuration file, and no edit of the harness: the weights, the
+    reference's ranking scores and the work count all come from
+    ``models/<ranking>.py``."""
+    here = _copy_harness(tmp_path)
+    (here / "models" / "toy.py").write_text(TOY_MODEL)
+    raw = json.loads((here / "configs" / "svq16k.json").read_text())
+    raw.update(ranking="toy", n_clusters=16, n_items=1024, n_users=256,
+               clusters_per_query=4, candidates_out=16)
+    (here / "configs" / "toy.json").write_text(json.dumps(raw))
+    toy = run.load_config(here / "configs" / "toy.json")
+
+    params = corpus.make_params(corpus.key_of(3), toy, here)
+    assert set(params["rank"]) == {"scale"}
+    two_tower = dataclasses.replace(toy, ranking="two_tower")
+    assert work.serve_user_flops(toy, here) == (
+        work.serve_user_flops(two_tower)
+        - rankers.ranking_model("two_tower").rank_flops(two_tower) + 7)
+
+    ids = jnp.asarray(corpus.corpus_ids(toy.n_items))
+    corp = corpus.make_corpus(corpus.key_of(4), params, ids,
+                              toy.n_clusters)
+    ref = reference.Reference(toy, params, corp, here=here)
+    rng = np.random.default_rng(0)
+    hist = rng.integers(0, toy.n_items, (2, toy.user_hist_len))
+    item_ids = np.asarray(corp.ids)[:6].reshape(2, 3)
+    _, got = ref.score_items(ref.user(np.arange(2), hist, 0), item_ids, 0)
+    table = np.asarray(params["tables"]["item_id"], np.float64)
+    want = float(params["rank"]["scale"][0]) \
+        * table[corpus.hash_ids_np(item_ids, toy.n_items)].sum(-1) \
+        + table[corpus.hash_ids_np(hist, toy.n_items)].sum((-2, -1))[:, None]
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("use", ["make_params", "serve_user_flops",
+                                 "Reference"])
+def test_a_ranking_with_no_model_file_is_an_error(tmp_path, use):
+    cfg = svq.CONFIG.with_(ranking="absent")
+    calls = {
+        "make_params": lambda: corpus.make_params(corpus.key_of(1), cfg,
+                                                  tmp_path),
+        "serve_user_flops": lambda: work.serve_user_flops(cfg, tmp_path),
+        "Reference": lambda: reference.Reference(cfg, None, None,
+                                                 here=tmp_path),
+    }
+    with pytest.raises(FileNotFoundError, match=r"models/absent\.py"):
+        calls[use]()
 
 
 def test_a_mix_of_an_unknown_kind_is_refused(tmp_path):

@@ -11,6 +11,8 @@ import json
 from pathlib import Path
 from typing import Dict, Sequence, Tuple
 
+import rankers
+
 F32 = 4
 I32 = 4
 
@@ -52,21 +54,18 @@ def top_k(batch: int, n: int, n_top: int) -> Tuple[int, int]:
     return 0, batch * n * F32 + batch * n_top * (F32 + I32)
 
 
-def serve_user_flops(cfg) -> int:
+def serve_user_flops(cfg, here: Path = rankers.HERE) -> int:
     """Model operations of serving one user for one task: the user tower,
     the cluster scores, the exact scores of the candidates, and the
-    two-tower ranking of every candidate."""
+    ranking of every candidate by the configuration's ranking model
+    (``rank_flops`` of ``models/<cfg.ranking>.py`` under ``here``)."""
     d_user_in = cfg.user_embed_dim + cfg.item_embed_dim
-    d_item_in = 2 * cfg.item_embed_dim
     s, d = cfg.candidates_out, cfg.embed_dim
     tower = mlp_flops(d_user_in, cfg.user_tower[:-1] + (cfg.embed_dim,))
     clusters = 2 * cfg.n_clusters * d
     exact = 2 * s * d
-    rank_user = mlp_flops(d_user_in, cfg.ranking_mlp)
-    rank_item = s * mlp_flops(d_item_in, cfg.ranking_mlp[:-1]
-                              + (cfg.ranking_mlp[-1] + 1,))
-    rank_dot = 2 * s * cfg.ranking_mlp[-1]
-    return tower + clusters + exact + rank_user + rank_item + rank_dot
+    rank = rankers.ranking_model(cfg.ranking, here).rank_flops(cfg)
+    return tower + clusters + exact + rank
 
 
 def roofline_s(flops: int, bytes_: int, peak: Dict) -> Tuple[float, str]:
