@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import SVQConfig
 from repro.models.dense import init_linear, init_mlp, linear, mlp
+from repro.obs import trace
 
 Params = Dict[str, Any]
 
@@ -59,23 +60,25 @@ def init_ranking(key: jax.Array, cfg: SVQConfig, d_user_in: int,
 
 def _mha_pool(attn: Params, task_idx: int, item_feat: jax.Array,
               hist_emb: jax.Array, n_heads: int) -> jax.Array:
-    """Target attention: item query over user behavior sequence."""
+    """Target attention: each candidate's item query over the user's
+    behaviour sequence.  item_feat (B, S, d_i), hist_emb (B, H, d_e) ->
+    (B, S, d_e).  K and V are projected once per user, not per
+    candidate."""
     wq = jax.tree_util.tree_map(lambda x: x[task_idx], attn["wq"])
     wk = jax.tree_util.tree_map(lambda x: x[task_idx], attn["wk"])
     wv = jax.tree_util.tree_map(lambda x: x[task_idx], attn["wv"])
-    q = linear(wq, item_feat)                    # (..., d_e)
-    k = linear(wk, hist_emb)                     # (..., H, d_e)
+    q = linear(wq, item_feat)                    # (B, S, d_e)
+    k = linear(wk, hist_emb)                     # (B, H, d_e)
     v = linear(wv, hist_emb)
-    d_e = q.shape[-1]
+    b, s, d_e = q.shape
     hd = d_e // n_heads
-    qh = q.reshape(q.shape[:-1] + (n_heads, hd))
-    kh = k.reshape(k.shape[:-2] + (k.shape[-2], n_heads, hd))
-    vh = v.reshape(vh_shape := kh.shape)
-    del vh_shape
-    logits = jnp.einsum("...hd,...shd->...hs", qh, kh) / jnp.sqrt(hd)
-    w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("...hs,...shd->...hd", w, vh)
-    return out.reshape(out.shape[:-2] + (d_e,))
+    qh = q.reshape(b, s, n_heads, hd)
+    kh = k.reshape(b, k.shape[1], n_heads, hd)
+    vh = v.reshape(kh.shape)
+    logits = jnp.einsum("bsnd,bhnd->bsnh", qh, kh) / jnp.sqrt(hd)
+    w = jax.nn.softmax(logits, axis=-1)          # over the history
+    out = jnp.einsum("bsnh,bhnd->bsnd", w, vh)
+    return out.reshape(b, s, d_e)
 
 
 def ranking_scores(p: Params, cfg: SVQConfig, user_feat: jax.Array,
@@ -101,23 +104,19 @@ def ranking_scores(p: Params, cfg: SVQConfig, user_feat: jax.Array,
             else:
                 score = jnp.sum(ru * rv, axis=-1) + rb
         else:
-            if serving:
-                s = item_feat.shape[1]
-                att = _mha_pool(p["attn"], t, item_feat,
-                                jnp.broadcast_to(
-                                    hist_emb[:, None],
-                                    (hist_emb.shape[0], s) + hist_emb.shape[1:]),
+            # training ranks one candidate per user: a unit S axis
+            cand = item_feat if serving else item_feat[:, None]
+            cross = cross_feat if serving else cross_feat[:, None]
+            with trace.annotate("rank_attn"):
+                att = _mha_pool(p["attn"], t, cand, hist_emb,
                                 cfg.ranking_heads)
+            with trace.annotate("rank_head"):
                 uf = jnp.broadcast_to(user_feat[:, None],
-                                      (user_feat.shape[0], s,
-                                       user_feat.shape[-1]))
-                cat = jnp.concatenate([uf, item_feat, att, cross_feat], -1)
-            else:
-                att = _mha_pool(p["attn"], t, item_feat, hist_emb,
-                                cfg.ranking_heads)
-                cat = jnp.concatenate(
-                    [user_feat, item_feat, att, cross_feat], -1)
-            hm = jax.tree_util.tree_map(lambda x: x[t], p["head"])
-            score = mlp(hm, cat)[..., 0]
+                                      cand.shape[:2] + user_feat.shape[-1:])
+                cat = jnp.concatenate([uf, cand, att, cross], -1)
+                hm = jax.tree_util.tree_map(lambda x: x[t], p["head"])
+                score = mlp(hm, cat)[..., 0]
+            if not serving:
+                score = score[:, 0]
         outs.append(score)
     return jnp.stack(outs)

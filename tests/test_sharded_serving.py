@@ -21,6 +21,7 @@ import pytest
 
 from repro.configs import get_smoke
 from repro.core import assignment_store as astore
+from repro.core import ranking as ranking_lib
 from repro.core import retriever
 from repro.data import RecsysStream, StreamConfig
 from repro.launch.mesh import make_serving_mesh
@@ -90,17 +91,30 @@ def test_shard_requires_divisible_clusters(trained):
         shard_serving_index(idx, cfg.n_clusters, 7)
 
 
+def _ranked_by(trained, ranking):
+    """The trained retriever with its ranking step replaced by a freshly
+    drawn ``ranking`` model (the same towers, tables and index)."""
+    cfg, params, index, idx, batch, stream, users = trained
+    if ranking != cfg.ranking:
+        cfg = cfg.with_(ranking=ranking)
+        params = dict(params, rank=ranking_lib.init_ranking(
+            jax.random.PRNGKey(5), cfg, *retriever.d_feature_dims(cfg)))
+    return cfg, params, index, idx, batch
+
+
 @pytest.mark.parametrize("n_shards", [1, 4, 8])
 @pytest.mark.parametrize("use_kernel", [False, True])
-def test_sharded_serve_bitexact(trained, n_shards, use_kernel):
-    cfg, params, index, idx, batch, stream, users = trained
+@pytest.mark.parametrize("ranking", ["two_tower", "complicated"])
+def test_sharded_serve_bitexact(trained, n_shards, use_kernel, ranking):
+    cfg, params, index, idx, batch = _ranked_by(trained, ranking)
     ref = retriever.serve(params, index, cfg, idx, batch,
                           use_kernel=use_kernel)
     sidx = shard_serving_index(idx, cfg.n_clusters, n_shards,
                                cap_quantum=64)
     got = sharded_serve(params, index, cfg, sidx, batch,
                         use_kernel=use_kernel)
-    _assert_same_outputs(ref, got, f"D={n_shards} uk={use_kernel}")
+    _assert_same_outputs(ref, got,
+                         f"D={n_shards} uk={use_kernel} {ranking}")
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
