@@ -72,8 +72,8 @@ def run() -> list:
     record = {"shape": dict(C=C, L=L, target=TARGET, batch=B, d=D_EMB),
               "backend": jax.default_backend(), "rows": {}}
     for chunk in (1, 4, 8, 16):
-        fn = jax.jit(lambda a, b, c, ch=chunk: merge_sort.merge_sort_serve(
-            a, b, c, ch, TARGET))
+        fn = jax.jit(lambda a, b, c, ch=chunk: ref.merge_serve_ref(
+            a[None], b[None], c[None], ch, TARGET)[0])
         us, (pos, _) = timed(fn, jcs, jbl, jln, n=5)
         got = set(np.asarray(pos)[np.asarray(pos) >= 0].tolist())
         overlap = len(got & want) / max(len(want), 1)
@@ -102,8 +102,8 @@ def run() -> list:
         -rng.normal(size=(B, C, L)).astype(np.float32), axis=-1))
     bln = jnp.asarray(rng.integers(L // 2, L + 1, (B, C))
                       .astype(np.int32))
-    scan_fn = jax.jit(jax.vmap(
-        lambda a, b, c: merge_sort.merge_sort_serve(a, b, c, 8, TARGET)))
+    scan_fn = jax.jit(
+        lambda a, b, c: ref.merge_serve_ref(a, b, c, 8, TARGET))
     us_scan, (pos_s, sc_s) = timed(scan_fn, bcs, bbl, bln, n=3)
     rows.append((f"merge_sort/lax_scan_B{B}_us", round(us_scan, 1),
                  "vmapped scan, chunk=8"))

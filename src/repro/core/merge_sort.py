@@ -14,6 +14,8 @@ clusters_per_query, e.g. 128).  We implement Alg. 1 as a lax.scan of S/l
 steps, each doing an argmax over C head scores carried in the scan's
 state: a pop reads its chunk and the popped cluster's next head, and
 refreshes that one head, so no step re-reads the other C - 1 heads.
+Pops read the index's flat bias array at each cluster's offset, so no
+(C, L) copy of the probed lists is built.
 Bit-identical pop order to the heap (ties go to the lowest cluster, as
 the heap's (-score, cluster) order), fully vectorizable and vmappable
 over queries.  A numpy heapq oracle is kept for verification and the
@@ -68,24 +70,39 @@ def merge_sort_serve_np(cluster_scores: np.ndarray,
     return out_pos, out_score
 
 
-@partial(jax.jit, static_argnames=("chunk", "target", "exact"))
+@partial(jax.jit, static_argnames=("chunk", "target", "l", "exact"))
 def merge_sort_serve(cluster_scores: jax.Array,
-                     bias_lists: jax.Array,
+                     starts: jax.Array,
                      lengths: jax.Array,
+                     limits: jax.Array,
+                     bias_flat: jax.Array,
                      chunk: int,
                      target: int,
+                     l: int,
                      exact: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """TPU-native Alg. 1: scan of (argmax over heads, take chunk).
+    """TPU-native Alg. 1 over a flat index: scan of (argmax over heads,
+    take chunk).
 
-    Same arguments as the numpy oracle; returns (positions, scores) padded
-    with (-1, NEG) if fewer than ``target`` items exist.  vmap over the
-    leading axis for batched queries.
+    cluster_scores/starts/lengths/limits: (C,) with ``starts[c]`` the
+    flat address of cluster c's first item and ``limits[c]`` the
+    address its reads are clamped to; bias_flat: (N,).  Item i of
+    cluster c is ``bias_flat[min(starts[c] + i, limits[c])]``.  Returns
+    (positions, scores), (target,) each, padded with (-1, NEG) if fewer
+    than ``target`` items exist; positions are encoded ``c * l + i`` as
+    in the numpy oracle over an (C, l) list.  vmap over the leading axis
+    for batched queries, closing over ``bias_flat`` where the queries
+    share one index.
+
+    A (C, L) slab, the Pallas ``merge_serve``'s input form, is the flat
+    array ``slab.reshape(-1)`` with ``starts = c * L`` and ``limits =
+    c * L + L - 1`` (``kernels/ref.py: merge_serve_ref``).
 
     The scan carries each cluster's pointer ``ptr`` and head bias
-    ``head_b = bias_lists[c, min(ptr[c], L - 1)]``.  A pop of cluster
-    ``c`` reads its chunk and its next head from row ``c`` of
-    ``bias_lists`` and refreshes ``head_b[c]`` alone: per pop the slab is
-    read at ``chunk + 1`` places, not at every cluster's head.
+    ``head_b`` (its item ``ptr[c]``, clamped).  A pop of cluster ``c``
+    reads its chunk and its next head, ``chunk + 1`` values from
+    ``starts[c] + ptr[c]`` on, and refreshes ``head_b[c]`` alone: per
+    pop the index is read at ``chunk + 1`` places, not at every
+    cluster's head.
 
     ``exact=True`` budgets ceil(target/chunk) + C pops (each pop either
     yields a full chunk or exhausts one of the C clusters, so this bound
@@ -93,10 +110,12 @@ def merge_sort_serve(cluster_scores: jax.Array,
     ceil(target/chunk) pops -- cheaper, may under-fill when many clusters
     hold < chunk items.
     """
-    C, L = bias_lists.shape
+    C = cluster_scores.shape[0]
     n_steps = -(-target // chunk) + (C if exact else 0)
     offsets = jnp.arange(chunk + 1)
     iota_c = jnp.arange(C)
+    # a pop reads its cluster's start, limit and length as one row
+    rows = jnp.stack([starts, limits, lengths], axis=-1)      # (C, 3)
 
     def step(carry, _):
         ptr, head_b, n_out = carry
@@ -104,20 +123,22 @@ def merge_sort_serve(cluster_scores: jax.Array,
         c = jnp.argmax(scores)
         hit = iota_c == c
         base = ptr[c]
+        start, limit, length = rows[c]
         # the chunk and, in its last slot, the popped cluster's next head
         idx = base + offsets
-        vals = bias_lists[c, jnp.minimum(idx, L - 1)]
+        vals = bias_flat[jnp.minimum(start + idx, limit)]
         idx = idx[:chunk]
-        valid = ((idx < lengths[c]) & (scores[c] > NEG / 2)
+        valid = ((idx < length) & (scores[c] > NEG / 2)
                  & (n_out < target))
-        pos = jnp.where(valid, c * L + idx, -1)
+        pos = jnp.where(valid, c * l + idx, -1)
         sc = jnp.where(valid, cluster_scores[c] + vals[:chunk], NEG)
         ptr = jnp.where(hit, ptr + chunk, ptr)
         head_b = jnp.where(hit, vals[chunk], head_b)
         return (ptr, head_b, n_out + jnp.sum(valid)), (pos, sc)
 
     ptr0 = jnp.zeros((C,), jnp.int32)
-    _, (pos, sc) = jax.lax.scan(step, (ptr0, bias_lists[:, 0], jnp.int32(0)),
+    head0 = bias_flat[jnp.minimum(starts, limits)]
+    _, (pos, sc) = jax.lax.scan(step, (ptr0, head0, jnp.int32(0)),
                                 None, length=n_steps)
     pos, sc = pos.reshape(-1), sc.reshape(-1)
     # Compact valid entries forward, preserving pop order (matches the
@@ -149,7 +170,8 @@ def fused_gather_rank_lax(u: jax.Array, cluster_scores: jax.Array,
     flat addresses and ``limits`` the per-lane clamp bound;
     bias_flat/ids_flat: (N,); emb_flat: (N, d).  Returns
     (pos, merge_scores, cand_ids, exact_scores), each (target,), with
-    pos encoded ``c * l + idx`` like ``merge_sort_serve``.
+    pos encoded ``c * l + idx`` like ``merge_sort_serve``, whose
+    addressing this is, with the id and embedding work added.
     """
     C = cluster_scores.shape[0]
     n_steps = -(-target // chunk) + (C if exact else 0)

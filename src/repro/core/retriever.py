@@ -244,17 +244,38 @@ def rank_clusters(state: IndexState, u: jax.Array, n: int,
 
 def serve_kernel(top_scores: jax.Array, bias: jax.Array,
                  lengths: jax.Array, chunk: int, target: int,
-                 use_kernel: bool = False, exact: bool = True
+                 use_kernel: bool = False, exact: bool = True, *,
+                 starts: Optional[jax.Array] = None,
+                 limits: Optional[jax.Array] = None,
+                 l: Optional[int] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """Single dispatch point for the batched Alg. 1 merge stage.
 
-    (B, C) cluster scores, (B, C, L) pre-sorted bias slabs, (B, C)
-    lengths -> ((B, target) flat positions, (B, target) merge scores).
-    ``use_kernel=True`` runs the fused Pallas kernel (Mosaic on a TPU,
-    interpret mode on the CPU backend); the fallback vmaps the lax.scan
-    form.  Both are bit-identical
-    to the numpy heap oracle for ``exact=True``.
+    (B, C) cluster scores and lengths -> ((B, target) positions
+    ``c * l + i``, (B, target) merge scores), from one of two forms:
+
+    - the flat index (``starts`` given): ``bias`` is the index's (N,)
+      bias, item i of query b's probed cluster c is at
+      ``min(starts[b, c] + i, limits[b, c])``, and ``l`` is the list
+      cap.  The lax merge (``merge_sort.merge_sort_serve``) reads it in
+      place; no slab is built.
+    - (B, C, L) pre-sorted bias slabs, the Pallas kernel's input form.
+      The fallback reads each slab as a flat array through the same lax
+      merge (``kernels/ref.merge_serve_ref``).
+
+    ``use_kernel=True`` runs the Pallas kernel (Mosaic on a TPU,
+    interpret mode on the CPU backend), gathering the slab from the flat
+    form first.  All are bit-identical to the numpy heap oracle for
+    ``exact=True``.
     """
+    if starts is not None:
+        if not use_kernel:
+            return jax.vmap(
+                lambda cs, st, ln, lm: merge_sort.merge_sort_serve(
+                    cs, st, ln, lm, bias, chunk, target, l, exact))(
+                top_scores, starts, lengths, limits)
+        bias = bias[jnp.minimum(starts[..., None] + jnp.arange(l),
+                                limits[..., None])]          # (B, C, L)
     if use_kernel:
         from repro.kernels import ops as kops
         return kops.merge_serve(top_scores, bias, lengths, chunk, target,
@@ -321,29 +342,33 @@ def serve_stage_merge(cfg: SVQConfig, index: astore.ServingIndex,
                       items_per_cluster: int = 256,
                       use_kernel: bool = False,
                       fused: bool = False) -> Dict[str, jax.Array]:
-    """Stage 2 of serve: slab fetch + Alg. 1 merge -> candidate ids.
+    """Stage 2 of serve: Alg. 1 merge over the flat index -> candidates.
 
-    ``fused=True`` skips the (B, C, L) bias-slab materialization: the
-    merge, candidate-id gather and exact Eq. 11 dot are fused into one
-    pass over the flat index arrays (pl.ds gathers in-kernel; the lax
-    fallback gathers per pop).  Bit-identical pos / merge_scores /
-    cand_ids; ``exact_scores`` matches the unfused gather+einsum to
-    float accumulation order.
+    The merge reads the flat ``index.item_bias`` by cluster offset:
+    item i of probed cluster c is at ``starts[c] + i``, its reads clamped
+    to ``min(starts[c] + L - 1, n_items - 1)``, which is exactly what a
+    (B, C, L) slab of the first L items would hold, so the default path
+    builds no slab.  ``use_kernel=True`` gathers that slab, the Pallas
+    ``merge_serve``'s input form, inside ``serve_kernel``; both paths
+    give bit-identical outputs.
+
+    ``fused=True`` fuses the merge, candidate-id gather and exact Eq. 11
+    dot into one pass over the flat index arrays (pl.ds gathers
+    in-kernel; the lax fallback gathers per pop).  Bit-identical pos /
+    merge_scores / cand_ids; ``exact_scores`` matches the unfused
+    gather+einsum to float accumulation order.
     """
     top_scores, top_clusters = s1["top_scores"], s1["top_clusters"]
     L = items_per_cluster
     S = cfg.candidates_out
+    n = index.n_items
     with trace.annotate("slab_gather"):
         starts = index.offsets[top_clusters]                 # (B, C)
         counts = index.counts[top_clusters]   # live prefix (tombstone-aware)
         lengths = jnp.minimum(counts, L)
-        if not fused:
-            slab = starts[..., None] + jnp.arange(L)[None, None, :]
-            slab = jnp.minimum(slab, index.n_items - 1)      # (B, C, L)
-            bias = index.item_bias[slab]                     # (B, C, L)
 
     if fused:
-        limits = jnp.full_like(starts, index.n_items - 1)
+        limits = jnp.full_like(starts, n - 1)
         with trace.annotate("fused_gather_rank"):
             pos, msort_scores, cand_ids, exact_scores = fused_gather_rank(
                 s1["u"], top_scores, starts, lengths, limits,
@@ -354,16 +379,17 @@ def serve_stage_merge(cfg: SVQConfig, index: astore.ServingIndex,
 
     # ---- Alg. 1 merge sort over (cluster personality + item bias) ------
     with trace.annotate("merge_serve"):
-        pos, msort_scores = serve_kernel(top_scores, bias, lengths,
-                                         cfg.chunk_size, S,
-                                         use_kernel=use_kernel)
+        pos, msort_scores = serve_kernel(
+            top_scores, index.item_bias, lengths, cfg.chunk_size, S,
+            use_kernel=use_kernel, starts=starts,
+            limits=jnp.minimum(starts + (L - 1), n - 1), l=L)
     with trace.annotate("cand_gather"):
         valid = pos >= 0
         c_idx = jnp.clip(pos, 0) // L
         i_idx = jnp.clip(pos, 0) % L
-        flat = jnp.take_along_axis(
-            slab.reshape(slab.shape[0], -1),
-            (c_idx * L + i_idx).astype(jnp.int32), axis=1)   # (B, S)
+        # the slab's own address of (c, i), clamp included
+        flat = jnp.minimum(
+            jnp.take_along_axis(starts, c_idx, axis=1) + i_idx, n - 1)
         cand_ids = index.item_ids[flat]
         # exact Eq. 11 candidate score u.v + bias from the index payload
         # — what the fused path computes in-kernel (the ranking step
@@ -414,8 +440,9 @@ def serve(params: Params, state: IndexState, cfg: SVQConfig,
 
     Composes the three stage functions (rank -> merge -> ranking); under
     one jit this traces exactly the pre-split op sequence.  ``fused``
-    selects the slab-free merge+gather+rank stage 2 (bit-identical
-    candidates; exact_scores allclose).
+    selects the merge+gather+rank stage 2, which also gathers and scores
+    the candidates inside the merge (bit-identical candidates;
+    exact_scores allclose).
     """
     s1 = serve_stage_rank(params, state, cfg, batch, task=task,
                           use_kernel=use_kernel)

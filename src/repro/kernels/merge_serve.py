@@ -63,7 +63,8 @@ vector in a kernel body is rank 2.  With C=128, L=256 f32 a query's
 lists are 128 KiB of VMEM.
 
 The pure-lax fallbacks (``kernels/ref.py: merge_serve_ref`` /
-``fused_gather_rank_ref``) vmap the ``lax.scan`` implementations;
+``fused_gather_rank_ref``) vmap the ``lax.scan`` implementations, which
+read flat arrays (a slab is read as one, cluster c at ``c * L``);
 ``core/retriever.serve_kernel`` / ``retriever.fused_gather_rank`` are
 the single dispatch points that pick Pallas vs fallback via
 ``use_kernel``.  ``kernels/ops.py`` compiles these with Mosaic on a TPU
@@ -299,9 +300,10 @@ def merge_serve_pallas(cluster_scores: jax.Array, bias_lists: jax.Array,
                        ) -> Tuple[jax.Array, jax.Array]:
     """Batched Alg. 1: (B,C), (B,C,L), (B,C) -> ((B,target) pos, scores).
 
-    Bit-identical to ``vmap(core.merge_sort.merge_sort_serve)`` (and, for
-    ``exact=True``, to the numpy heap oracle): same pop order, same
-    (-1, NEG) padding, same stable forward compaction.
+    Bit-identical to ``kernels/ref.merge_serve_ref``, which reads each
+    slab as a flat array through ``core.merge_sort.merge_sort_serve``
+    (and, for ``exact=True``, to the numpy heap oracle): same pop order,
+    same (-1, NEG) padding, same stable forward compaction.
     """
     l = bias_lists.shape[-1]
     return _merge_call(_merge_serve_kernel, cluster_scores,

@@ -58,11 +58,19 @@ def cluster_rank_ref(u: jax.Array, e: jax.Array, n: int
 def merge_serve_ref(cluster_scores: jax.Array, bias_lists: jax.Array,
                     lengths: jax.Array, chunk: int, target: int,
                     exact: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """Batched Alg. 1 merge: vmapped lax.scan reference (the pure-lax
-    fallback `core/retriever.serve_kernel` dispatches to)."""
+    """Batched Alg. 1 merge over (B, C, L) bias slabs, the Pallas
+    ``merge_serve``'s input form (the pure-lax fallback
+    ``core/retriever.serve_kernel`` dispatches to).
+
+    Each query's slab is read as a flat array by the one lax merge,
+    ``core/merge_sort.merge_sort_serve``: cluster c starts at ``c * L``
+    and its reads are clamped to ``c * L + L - 1``."""
     from repro.core import merge_sort   # lazy: avoid core <-> kernels cycle
+    L = bias_lists.shape[-1]
+    starts = jnp.arange(bias_lists.shape[-2], dtype=jnp.int32) * L
+    limits = starts + (L - 1)
     return jax.vmap(lambda cs, bl, ln: merge_sort.merge_sort_serve(
-        cs, bl, ln, chunk, target, exact))(
+        cs, starts, ln, limits, bl.reshape(-1), chunk, target, L, exact))(
         cluster_scores, bias_lists, lengths)
 
 

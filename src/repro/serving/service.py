@@ -59,8 +59,8 @@ class RetrievalService:
         self.cfg = cfg
         self.items_per_cluster = items_per_cluster
         self.use_kernel = use_kernel
-        # fused=True serves through the slab-free merge+gather+rank
-        # stage (bit-identical candidates; adds exact_scores in-pass)
+        # fused=True serves through the merge+gather+rank stage
+        # (bit-identical candidates; adds exact_scores in-pass)
         self.fused = fused
         self.n_shards = n_shards
         self.mesh = mesh

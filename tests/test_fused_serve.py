@@ -21,7 +21,7 @@ import pytest
 
 from repro.configs.base import SVQConfig
 from repro.core import assignment_store as astore
-from repro.core import retriever
+from repro.core import merge_sort, retriever
 from repro.kernels import ops, ref
 from repro.serving import RetrievalService, sharding
 
@@ -184,6 +184,47 @@ def test_serve_fused_parity(trained):
                 params, state, cfg, index, sbatch, items_per_cluster=32,
                 use_kernel=uk, fused=fused))
             _assert_outputs_match(want, got, f"fused={fused},uk={uk}")
+
+
+@pytest.mark.parametrize("L", [4, 32])
+def test_stage_merge_flat_matches_slab_form(trained, L):
+    """The default stage 2 merges the flat index by cluster offset
+    (``serve_kernel``'s flat form); its pos, merge_scores, cand_ids and
+    exact_scores equal those of the (B, C, L) slab form merged through
+    ``serve_kernel`` (lax and Pallas), bit for bit.  L = 4 cuts long
+    clusters, L = 32 reads past short ones into their neighbours'
+    slots."""
+    cfg, params, state, index, sbatch = trained
+    s1 = retriever.serve_stage_rank(params, state, cfg, sbatch)
+    got = retriever.serve_stage_merge(cfg, index, s1, items_per_cluster=L)
+    n, S = index.n_items, cfg.candidates_out
+    starts = index.offsets[s1["top_clusters"]]
+    lengths = jnp.minimum(index.counts[s1["top_clusters"]], L)
+    limits = jnp.minimum(starts + (L - 1), n - 1)
+    pos, sc = retriever.serve_kernel(
+        s1["top_scores"], index.item_bias, lengths, cfg.chunk_size, S,
+        starts=starts, limits=limits, l=L)
+    slab = jnp.minimum(starts[..., None] + jnp.arange(L), n - 1)
+    for uk in (False, True):
+        pos_s, sc_s = retriever.serve_kernel(
+            s1["top_scores"], index.item_bias[slab], lengths,
+            cfg.chunk_size, S, use_kernel=uk)
+        flat = jnp.take_along_axis(slab.reshape(slab.shape[0], -1),
+                                   jnp.clip(pos_s, 0), axis=1)
+        valid = pos_s >= 0
+        exact = jnp.where(
+            valid,
+            jnp.einsum("bsd,bd->bs", index.item_emb[flat], s1["u"])
+            + index.item_bias[flat], merge_sort.NEG)
+        want = dict(cand_ids=index.item_ids[flat], valid=valid,
+                    merge_scores=sc_s, exact_scores=exact)
+        np.testing.assert_array_equal(np.asarray(pos), np.asarray(pos_s))
+        np.testing.assert_array_equal(np.asarray(sc), np.asarray(sc_s))
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(want[k]),
+                                          err_msg=f"uk={uk}:{k}")
+    assert int(np.asarray(got["valid"]).sum()) > 0
 
 
 def test_sharded_serve_fused_parity(trained):

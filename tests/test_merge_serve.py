@@ -1,7 +1,8 @@
 """Fused Pallas serving path vs oracles: bit-exact Alg. 1 parity.
 
 Three-way parity on pop order for every case: numpy heap oracle
-(`merge_sort_serve_np`) == lax.scan (`merge_sort_serve`, exact=True) ==
+(`merge_sort_serve_np`) == lax.scan (`merge_sort_serve` over the slab
+read as a flat array, exact=True) ==
 Pallas kernel (`ops.merge_serve`, interpret mode), plus cluster_rank
 against `lax.top_k(u @ e.T, n)` and the `retriever.serve_kernel`
 dispatch equivalence.
@@ -15,6 +16,8 @@ import numpy as np
 import pytest
 from _hypo import given, settings, st
 
+from repro.configs.base import SVQConfig
+from repro.core import assignment_store as astore
 from repro.core import merge_sort, retriever
 from repro.kernels import ops, ref
 
@@ -24,8 +27,9 @@ def _assert_three_way(cs, bl, ln, chunk, target):
     jcs, jbl, jln = map(jnp.asarray, (cs, bl, ln))
     pos_np, sc_np = merge_sort.merge_sort_serve_np(cs, bl, ln, chunk,
                                                    target)
-    pos_j, sc_j = merge_sort.merge_sort_serve(jcs, jbl, jln, chunk,
-                                              target, exact=True)
+    pos_j, sc_j = ref.merge_serve_ref(jcs[None], jbl[None], jln[None],
+                                      chunk, target, exact=True)
+    pos_j, sc_j = pos_j[0], sc_j[0]
     pos_p, sc_p = ops.merge_serve(jcs[None], jbl[None], jln[None],
                                   chunk, target)
     pos_p, sc_p = np.asarray(pos_p[0]), np.asarray(sc_p[0])
@@ -206,6 +210,62 @@ def test_carried_heads_match_heap_oracle(rng, case):
     _assert_lax_batch_bit_exact(*case(rng))
 
 
+def _flat_index_case(rng, c, l, chunk, tied):
+    """``c`` probed clusters laid out in one shared flat bias array, as
+    the serving index lays its segments: sorted descending, spare slots
+    between them, and the last segment ending at N - 1.  Live counts
+    cover 0, 1, chunk - 1, chunk, L and beyond L (cut to L), and the
+    probe order is unrelated to the addresses."""
+    counts = rng.permutation(np.resize(
+        [0, 1, chunk - 1, chunk, l, l + 3, int(rng.integers(0, 2 * l))],
+        c)).astype(np.int32)
+    counts[-1] = min(max(int(counts[-1]), 1), l)  # ends at N - 1
+    gaps = rng.integers(0, 4, size=c)
+    seg = np.cumsum(gaps + np.concatenate([[0], counts[:-1]]))
+    n = int(seg[-1] + counts[-1])
+    draw = ((lambda k: rng.integers(0, 3, size=k).astype(np.float32))
+            if tied else
+            (lambda k: rng.normal(size=k).astype(np.float32)))
+    bias = draw(n)                           # spare slots hold garbage
+    for s0, k in zip(seg, counts):
+        bias[s0:s0 + k] = -np.sort(-bias[s0:s0 + k])
+    order = rng.permutation(c)
+    starts = seg[order].astype(np.int32)
+    lengths = np.minimum(counts[order], l).astype(np.int32)
+    limits = np.minimum(starts + l - 1, n - 1).astype(np.int32)
+    cs = (rng.integers(0, 2, size=c) if tied
+          else rng.normal(size=c)).astype(np.float32)
+    return cs, starts, lengths, limits, bias
+
+
+@pytest.mark.parametrize("c,l,chunk,target,tied", [
+    (12, 16, 4, 64, False),
+    (12, 16, 4, 64, True),                # ties across and within lists
+    (9, 8, 3, 10 ** 3, False),            # target beyond every live item
+    (14, 24, 8, 40, True),
+    (7, 5, 8, 30, False),                 # chunk longer than L
+])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_flat_index_matches_heap_oracle(rng, c, l, chunk, target, tied):
+    """``merge_sort_serve`` over a flat index == the heap oracle over the
+    (C, L) lists those addresses hold, bit for bit, padding included."""
+    cs, starts, lengths, limits, bias = _flat_index_case(rng, c, l, chunk,
+                                                         tied)
+    assert limits.max() == bias.shape[0] - 1
+    bl = bias[np.minimum(starts[:, None] + np.arange(l), limits[:, None])]
+    pos_np, sc_np = merge_sort.merge_sort_serve_np(cs, bl, lengths, chunk,
+                                                   target)
+    pos, sc = merge_sort.merge_sort_serve(
+        *map(jnp.asarray, (cs, starts, lengths, limits, bias)),
+        chunk, target, l)
+    pos, sc = np.asarray(pos), np.asarray(sc)
+    n = len(pos_np)
+    np.testing.assert_array_equal(pos[:n], pos_np)
+    np.testing.assert_array_equal(sc[:n].astype(np.float64), sc_np)
+    assert np.all(pos[n:] == -1)
+    assert np.all(sc[n:] == np.float32(merge_sort.NEG))
+
+
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
 _HLO_CALLEE = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)")
 _HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
@@ -266,6 +326,34 @@ def test_merge_loop_gathers_no_per_cluster_heads():
     assert gathers, "no gather found in the merge's while body"
     scalar = [n for n, win in gathers if win == 1]
     assert max(scalar) < B * C, gathers
+
+
+def test_default_serve_stage_builds_no_slab():
+    """At the serve cells' shapes (B = 512, C = 128, L = 256, a 2^21-slot
+    index), the compiled default merge stage holds no gather of B x C x L
+    slices: the merge reads the flat index by cluster offset.  (Slices,
+    not elements: the candidates' (B, S, d) embedding gather has as many
+    elements at these shapes.)"""
+    B, C, L = 512, 128, 256
+    cfg = SVQConfig()
+    N, K, d = cfg.n_items, cfg.n_clusters, cfg.embed_dim
+    f32, i32 = jnp.float32, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    index = astore.ServingIndex(
+        item_ids=sds((N,), i32), item_emb=sds((N, d), f32),
+        item_bias=sds((N,), f32), cluster_of=sds((N,), i32),
+        offsets=sds((K + 1,), i32), counts=sds((K,), i32))
+    s1 = dict(top_scores=sds((B, C), f32), top_clusters=sds((B, C), i32),
+              u=sds((B, d), f32))
+    assert cfg.clusters_per_query == C
+    stage = jax.jit(partial(retriever.serve_stage_merge, cfg,
+                            items_per_cluster=L))
+    hlo = stage.lower(index, s1).compile().as_text()
+    slices = [int(np.prod([int(x) for x in m.group(1).split(",")]))
+              // int(np.prod([int(x) for x in m.group(2).split(",")]))
+              for m in map(_HLO_GATHER.search, hlo.splitlines()) if m]
+    assert slices, "no gather found in the merge stage"
+    assert B * C * L not in slices, slices
 
 
 # ---------------------------------------------------------------------------
